@@ -4,8 +4,10 @@ end-to-end: asynchronous/periodic sync buys a ~tau reduction in parameter
 traffic at (near-)zero quality cost.
 
 Runs in a subprocess with 8 forced host devices (worker axis) so the main
-process keeps the single-device view. Results ->
-benchmarks/artifacts/ablation_sync.json.
+process keeps the single-device view. The child is a CPU simulation of 8
+workers, so its env pins ``JAX_PLATFORMS=cpu``: on a machine with a chip
+the parent process already holds it, and a child that asked for it
+would fail or hang. Results -> benchmarks/artifacts/ablation_sync.json.
 """
 
 from __future__ import annotations
@@ -68,6 +70,7 @@ def run():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"        # a simulation: leave the chip alone
     proc = subprocess.run([sys.executable, "-c", _CHILD],
                           capture_output=True, text=True, env=env,
                           timeout=900)

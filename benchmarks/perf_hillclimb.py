@@ -121,11 +121,10 @@ def dml_tau_variant(log, tau: int, comm_dtype: str, force=False):
         "ys": jax.ShapeDtypeStruct((tau, B, d), jnp.float32),
         "sim": jax.ShapeDtypeStruct((tau, B), jnp.int32),
     }
-    from repro.sharding.partition import shard_map
-    fn = shard_map(chunk_fn, mesh=mesh,
-                   in_specs=(P("model", None), P("data")),
-                   out_specs=(P("model", None), P()),
-                   check_vma=False)
+    fn = jax.shard_map(chunk_fn, mesh=mesh,
+                       in_specs=(P("model", None), P("data")),
+                       out_specs=(P("model", None), P()),
+                       check_vma=False)
     # global views for lowering: L (k, d), batches (data*tau, B, ...)
     L_g = jax.ShapeDtypeStruct((dcfg.proj_dim, d), jnp.float32)
     b_g = {

@@ -43,6 +43,8 @@ def main() -> None:
             results.append((name, f"FAIL:{type(e).__name__}",
                             time.time() - t0))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (ablation_sync, fig2_convergence, fig3_speedup,
                             fig4_quality, gallery_churn,
                             mining_convergence, retrieval_qps,

@@ -21,6 +21,7 @@ from repro.checkpoint import save_checkpoint, restore_checkpoint
 from repro.configs import dml_paper
 from repro.core import dml, losses
 from repro.core.ps import sync as ps_sync
+from repro.core.ps.trainer import stack_worker_streams
 from repro.data import pairs as pairdata
 from repro.optim import sgd, schedules
 
@@ -36,7 +37,8 @@ def main():
     ap.add_argument("--samples", type=int, default=10000,
                     help="synthetic stand-in for the 1M LLC images")
     ap.add_argument("--fused", action="store_true",
-                    help="use the Pallas fused pair-loss kernel (interpret)")
+                    help="use the Pallas fused pair-loss kernel (compiled on "
+                         "TPU, interpreted elsewhere)")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", type=str, default="/tmp/repro_imnet1m")
     args = ap.parse_args()
@@ -69,12 +71,9 @@ def main():
     if args.workers > 1:
         # partition pair indices over workers (paper §4.1) and run the SPMD
         # PS trainer under the chosen consistency model
-        n = train_idx["sim"].shape[0]
-        shards = np.array_split(np.arange(n), args.workers)
-        streams = [pairdata.pair_batches_from_indices(
-            features[:-n_hold],
-            {k: v[s] for k, v in train_idx.items()},
-            args.batch, seed=10 + i) for i, s in enumerate(shards)]
+        batches = stack_worker_streams(pairdata.IndexPairSource(
+            features[:-n_hold], train_idx).worker_streams(
+                args.workers, args.batch, seed=10))
         ps_cfg = ps_sync.PSConfig(n_workers=args.workers, sync=args.sync,
                                   tau=args.tau, staleness=max(2, args.tau))
         mesh = ps_sync.make_worker_mesh(args.workers)
@@ -85,10 +84,7 @@ def main():
                                               margin=exp.dml.margin),
             opt, ps_cfg, mesh)
         for t in range(args.steps):
-            batch = {k: jnp.stack([b[k] for b in
-                                   [next(s) for s in streams]])
-                     for k in ("xs", "ys", "sim")}
-            state, metrics = step_fn(state, batch)
+            state, metrics = step_fn(state, next(batches))
             hist.append({"step": t, "loss": float(metrics["loss"])})
             if t % 20 == 0:
                 print(f"  step {t}: loss={hist[-1]['loss']:.4f}", flush=True)

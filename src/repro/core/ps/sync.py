@@ -39,7 +39,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.optim import Optimizer, apply_updates
-from repro.sharding.partition import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,7 +196,7 @@ def make_train_step(loss_fn: Callable, opt: Optimizer, cfg: PSConfig,
     ring_spec = P(ax) if cfg.sync == "ssp" else None
     state_specs = PSState(params=P(ax), opt_state=P(ax), step=P(),
                           grad_ring=ring_spec, rng=P())
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         step_fn, mesh=mesh,
         in_specs=(state_specs, P(ax)),
         out_specs=(state_specs, P()),
@@ -251,10 +250,10 @@ def make_train_chunk(loss_fn: Callable, opt: Optimizer, cfg: PSConfig,
 
     state_specs = PSState(params=P(ax), opt_state=P(ax), step=P(),
                           grad_ring=None, rng=P())
-    shmapped = shard_map(chunk_fn, mesh=mesh,
-                         in_specs=(state_specs, P(ax)),
-                         out_specs=(state_specs, P()),
-                         check_vma=False)
+    shmapped = jax.shard_map(chunk_fn, mesh=mesh,
+                             in_specs=(state_specs, P(ax)),
+                             out_specs=(state_specs, P()),
+                             check_vma=False)
     return jax.jit(shmapped)
 
 

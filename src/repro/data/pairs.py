@@ -19,11 +19,14 @@ different-class pairs for D.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.data.loader import partition_pairs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +76,39 @@ def make_features(cfg: PairDatasetConfig) -> Tuple[np.ndarray, np.ndarray]:
     else:
         raise ValueError(f"unknown kind {cfg.kind}")
     return x, labels
+
+
+def llc_like_chunk(cfg: PairDatasetConfig, chunk: int, rows: int):
+    """One chunk of an ``llc_like`` feature stream, made on the device.
+
+    Same class structure as ``make_features``' llc_like — per-class
+    supports of density ``1 - sparsity``, |N(0,1)| class magnitudes,
+    ``noise * |N(0,1)|`` row noise inside the support — drawn with
+    jax.random from ``cfg.seed``. A chunk depends only on (seed, chunk,
+    rows), so a stream of any length (the paper's 1M x 21,504 is 86 GB)
+    is made chunk by chunk without ever being held whole, and disjoint
+    chunk ids give disjoint rows of the same distribution. Returns
+    (x (rows, feat_dim) f32, labels (rows,) int32) on the default device.
+    """
+    if cfg.kind != "llc_like":
+        raise ValueError(f"llc_like_chunk makes llc_like rows, not "
+                         f"{cfg.kind!r}")
+    return _llc_chunk(cfg.seed, chunk, cfg.sparsity, cfg.noise, rows=rows,
+                      n_classes=cfg.n_classes, feat_dim=cfg.feat_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "n_classes",
+                                             "feat_dim"))
+def _llc_chunk(seed, chunk, sparsity, noise, *, rows: int, n_classes: int,
+               feat_dim: int):
+    k_mag, k_sup, k_rows = jax.random.split(jax.random.PRNGKey(seed), 3)
+    mags = jnp.abs(jax.random.normal(k_mag, (n_classes, feat_dim)))
+    support = jax.random.uniform(k_sup, (n_classes, feat_dim)) < 1 - sparsity
+    k_lab, k_noise = jax.random.split(jax.random.fold_in(k_rows, chunk))
+    labels = jax.random.randint(k_lab, (rows,), 0, n_classes)
+    row_noise = noise * jnp.abs(jax.random.normal(k_noise, (rows, feat_dim)))
+    x = jnp.where(support[labels], mags[labels] + row_noise, 0.0)
+    return x, labels.astype(jnp.int32)
 
 
 def _draw_pair_indices(rng, labels: np.ndarray, n_pairs: int,
@@ -212,6 +248,28 @@ def pair_batches_from_indices(features: np.ndarray, idx_pairs: dict,
             "ys": jnp.asarray(features[idx_pairs["b"][sel]]),
             "sim": jnp.asarray(idx_pairs["sim"][sel]),
         }
+
+
+class IndexPairSource:
+    """A trainer pair source over a feature store and index pairs.
+
+    At the paper's scale pairs are kept as indices into the features
+    (``sample_pair_indices``) and gathered per batch. ``worker_streams``
+    is the pluggable-source contract of ``core/ps/trainer``: it
+    partitions the index pairs over workers (paper §4.1) and streams each
+    shard through ``pair_batches_from_indices``. ``features`` may be a
+    device array; the per-batch gathers then run on the device.
+    """
+
+    def __init__(self, features, idx_pairs: dict):
+        self.features = features
+        self.idx_pairs = idx_pairs
+
+    def worker_streams(self, n_workers: int, batch_size: int, seed: int):
+        return [pair_batches_from_indices(self.features, shard, batch_size,
+                                          seed=seed + i)
+                for i, shard in enumerate(
+                    partition_pairs(self.idx_pairs, n_workers))]
 
 
 def pair_batches(pairs: dict, batch_size: int, seed: int = 0,

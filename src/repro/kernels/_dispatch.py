@@ -18,6 +18,22 @@ import jax.numpy as jnp
 LANE = 128      # TPU lane width: last-dim tiles round up to this
 SUBLANE = 8     # f32 sublane width: second-minor tiles round up to this
 
+# Full f32 precision for the exact and IVF scans' cross terms (kernels
+# and references alike) and for the fused pair loss: their on-chip
+# parity and recall checks fail or lose their margin at the TPU's
+# default. Projections, PQ tables and the IVFPQ rerank keep the default.
+# docs/kernels.md "Precision on the TPU" gives the policy per path.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def matmul_t(a, b, precision=None):
+    """``a @ b.T`` in f32 (the projection and cross-term contraction
+    every scan path shares); ``precision`` as for ``lax.dot_general``."""
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        (((a.ndim - 1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+
 
 def check_metric_factor(L, d_in=None, *, what: str = "L"):
     """Validate the ``(d_out, d_in)`` metric-factor contract up front.

@@ -16,25 +16,18 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._dispatch import (HIGHEST, LANE, SUBLANE, matmul_t,
+                                     pad_axis, pick_block, round_up)
 from repro.kernels.dml_pair.kernel import dml_pair_fused
 from repro.kernels.dml_pair.ref import dml_pair_ref
 
 
-def _pad_to(x, mult, axis):
-    n = x.shape[axis]
-    pad = (-n) % mult
-    if pad == 0:
-        return x, n
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), n
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def dml_pair_loss_fused(L, xs, ys, sim, lam: float = 1.0, margin: float = 1.0,
-                        interpret: bool = True):
+                        interpret=None):
     """Mean Eq. 4 objective via the Pallas kernel. Differentiable w.r.t.
-    L, xs, ys (the latter two enable end-to-end deep metric learning)."""
+    L, xs, ys (the latter two enable end-to-end deep metric learning).
+    ``interpret`` None compiles on TPU and interprets elsewhere."""
     losses = _forward(L, xs, ys, sim, lam, margin, interpret)[0]
     return jnp.mean(losses)
 
@@ -42,21 +35,21 @@ def dml_pair_loss_fused(L, xs, ys, sim, lam: float = 1.0, margin: float = 1.0,
 def _forward(L, xs, ys, sim, lam, margin, interpret):
     k, d = L.shape
     B = xs.shape[0]
-    # pad to tile boundaries (sim=1, x=y=0 padding contributes zero loss)
-    bB = 256 if B >= 256 else max(8, B)
-    bK = 128 if k >= 128 else k
-    bD = 512 if d >= 512 else d
-    Lp, _ = _pad_to(L, bK, 0)
-    Lp, _ = _pad_to(Lp, bD, 1)
-    xsp, _ = _pad_to(xs, bD, 1)
-    ysp, _ = _pad_to(ys, bD, 1)
-    xsp, _ = _pad_to(xsp, bB, 0)
-    ysp, _ = _pad_to(ysp, bB, 0)
-    simp = jnp.pad(sim, (0, (-B) % bB), constant_values=1)
+    # pad to tile boundaries (sim=1, x=y=0 padding contributes zero loss;
+    # zero L rows/columns change no distance). A lane-dim tile under one
+    # block spans the whole dim, which is always a legal block.
+    bB = pick_block(B, 256, SUBLANE)
+    bK = min(k, LANE)
+    bD = min(d, 512)
+    kP, dP, BP = round_up(k, bK), round_up(d, bD), round_up(B, bB)
+    Lp = pad_axis(pad_axis(L, kP, 0), dP, 1)
+    xsp = pad_axis(pad_axis(xs, dP, 1), BP, 0)
+    ysp = pad_axis(pad_axis(ys, dP, 1), BP, 0)
+    simp = pad_axis(sim, BP, 0, value=1)[:, None]
     losses, d2, proj = dml_pair_fused(
         Lp, xsp, ysp, simp, lam=lam, margin=margin,
         block_b=bB, block_k=bK, block_d=bD, interpret=interpret)
-    return losses[:B], d2[:B], proj[:B, :k]
+    return losses[:B, 0], d2[:B, 0], proj[:B, :k]
 
 
 def _fwd(L, xs, ys, sim, lam, margin, interpret):
@@ -73,8 +66,8 @@ def _bwd(lam, margin, interpret, res, g):
     z = (xs - ys).astype(jnp.float32)
     scale = 2.0 * g / B
     pw = proj * w[:, None]                              # (B,k)
-    dL = scale * pw.T @ z                               # (k,d)
-    dz = scale * (pw @ L.astype(jnp.float32))           # (B,d)
+    dL = scale * matmul_t(pw.T, z.T, HIGHEST)           # (k,d)
+    dz = scale * matmul_t(pw, L.T, HIGHEST)             # (B,d)
     return (dL.astype(L.dtype), dz.astype(xs.dtype), (-dz).astype(ys.dtype),
             None)
 

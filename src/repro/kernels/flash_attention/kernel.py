@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._dispatch import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -79,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True):
+                    interpret=None):
     """q (B,T,H,Dh); k,v (B,S,K,Dh), H % K == 0. Returns (B,T,H,Dh)."""
     B, T, H, dh = q.shape
     S, K = k.shape[1], k.shape[2]
@@ -118,6 +120,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bQ,), jnp.float32),
             pltpu.VMEM((bQ, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(qt, kt, vt)
     return out.reshape(B, H, T, dh).transpose(0, 2, 1, 3)

@@ -11,7 +11,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 def attend_flash(q, k, v, *, causal: bool = True, window: int = 0,
                  block_q: int = 512, block_k: int = 512,
-                 interpret: bool = True):
+                 interpret=None):
     """Serving-path attention. Falls back to the oracle when tile shapes
     don't divide (tiny smoke configs)."""
     B, T, H, dh = q.shape

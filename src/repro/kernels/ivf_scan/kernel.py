@@ -11,7 +11,10 @@ per program row, probe/tile stream innermost, probe list as a
 scalar-prefetch operand so the gp/gn/id block index maps DMA the right
 (bM, k) segment tile per step, running (1, kk) best buffers in VMEM
 scratch, best-index init -1 (BIG-sentinel survivors must look like real
-pad candidates; ops.py masks and re-sorts). The only body difference is
+pad candidates; ops.py masks and re-sorts). Per-query rows, and each
+tile's row norms and ids, travel as (n, 1, ·) arrays whose (None, 1, ·)
+blocks equal their last two dims — a layout the TPU lowering accepts at
+any tile width. The only body difference is
 the score: an MXU dot of the (1, k) query row against the (bM, k) tile
 replaces the one-hot LUT accumulate — which also means the contraction
 over k is a genuine reduction, so distances match the XLA reference to
@@ -28,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._dispatch import HIGHEST, default_interpret
 from repro.kernels.metric_topk.kernel import BIG, _merge_topk
 
 
@@ -41,14 +45,13 @@ def _ivf_scan_kernel(probes_ref, qp_ref, g_ref, gn_ref, ids_ref,
         bi_ref[...] = jnp.full(bi_ref.shape, -1, jnp.int32)
 
     qp = qp_ref[...]                                     # (1, k)
-    qn = jnp.sum(jnp.square(qp), axis=1)                 # (1,)
+    qn = jnp.sum(jnp.square(qp), axis=1, keepdims=True)  # (1, 1)
     cross = jax.lax.dot_general(                         # (1, bM)
-        qp, g_ref[...],
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    d = jnp.maximum(qn[:, None] + gn_ref[...][None, :] - 2.0 * cross, 0.0)
+        qp, g_ref[...], (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
+    d = jnp.maximum(qn + gn_ref[...] - 2.0 * cross, 0.0)
 
-    bd, bi = _merge_topk(bd_ref[...], bi_ref[...], d,
-                         ids_ref[...][None, :], kk)
+    bd, bi = _merge_topk(bd_ref[...], bi_ref[...], d, ids_ref[...], kk)
     bd_ref[...] = bd
     bi_ref[...] = bi
 
@@ -61,25 +64,28 @@ def _ivf_scan_kernel(probes_ref, qp_ref, g_ref, gn_ref, ids_ref,
 @functools.partial(jax.jit, static_argnames=("cap", "kk", "block_m",
                                              "interpret"))
 def ivf_scan_topk_fused(probes, qp, g, gn, ids, *, cap: int, kk: int,
-                        block_m: int, interpret: bool = True):
+                        block_m: int, interpret=None):
     """Fused probed-segment scan + streaming top-k.
 
     Args:
       probes: (Nq, nprobe) int32 probed cluster ids (scalar-prefetch).
-      qp: (Nq, k) projected queries, k lane-padded with zeros.
-      g: (C*cap, k) segment rows (lane-padded to match qp);
-        gn: (C*cap,) row norms (+BIG pads); ids: (C*cap,) int32 ids
-        (-1 pads).
+      qp: (Nq, 1, k) projected queries.
+      g: (C*cap, k) segment rows;
+        gn: (C*cap/block_m, 1, block_m) row norms (+BIG pads) and ids
+        the same shape in int32 (-1 pads), one (1, block_m) row per tile.
       cap: rows per segment; block_m: rows per tile, must divide cap.
+      interpret: None compiles on TPU and interprets elsewhere.
 
-    Returns (dists (Nq, kk) f32, ids (Nq, kk) int32) in streaming-merge
-    order; ids at the BIG sentinel may repeat a knocked-out winner —
-    ops.py masks them to -1 before the final sort.
+    Returns (dists (Nq, 1, kk) f32, ids (Nq, 1, kk) int32) in
+    streaming-merge order; ids at the BIG sentinel may repeat a
+    knocked-out winner — ops.py masks them to -1 before the final sort.
     """
     Nq, nprobe = probes.shape
     rows, k = g.shape
+    assert qp.shape == (Nq, 1, k), (qp.shape, Nq, k)
     bM = block_m
     assert cap % bM == 0 and rows % cap == 0, (rows, cap, bM)
+    assert gn.shape == ids.shape == (rows // bM, 1, bM), (gn.shape, bM)
     assert kk <= nprobe * cap, (kk, nprobe, cap)
     nsteps = cap // bM          # tiles per probed segment
 
@@ -91,17 +97,18 @@ def ivf_scan_topk_fused(probes, qp, g, gn, ids, *, cap: int, kk: int,
         num_scalar_prefetch=1,
         grid=(Nq, nprobe * nsteps),
         in_specs=[
-            pl.BlockSpec((1, k), lambda q, j, pr: (q, 0)),   # qp row
+            pl.BlockSpec((None, 1, k),
+                         lambda q, j, pr: (q, 0, 0)),         # qp row
             pl.BlockSpec((bM, k),
                          lambda q, j, pr: (seg_row(q, j, pr), 0)),
-            pl.BlockSpec((bM,),
-                         lambda q, j, pr: (seg_row(q, j, pr),)),
-            pl.BlockSpec((bM,),
-                         lambda q, j, pr: (seg_row(q, j, pr),)),
+            pl.BlockSpec((None, 1, bM),
+                         lambda q, j, pr: (seg_row(q, j, pr), 0, 0)),
+            pl.BlockSpec((None, 1, bM),
+                         lambda q, j, pr: (seg_row(q, j, pr), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, kk), lambda q, j, pr: (q, 0)),
-            pl.BlockSpec((1, kk), lambda q, j, pr: (q, 0)),
+            pl.BlockSpec((None, 1, kk), lambda q, j, pr: (q, 0, 0)),
+            pl.BlockSpec((None, 1, kk), lambda q, j, pr: (q, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, kk), jnp.float32),   # running best distances
@@ -112,8 +119,8 @@ def ivf_scan_topk_fused(probes, qp, g, gn, ids, *, cap: int, kk: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Nq, kk), jnp.float32),
-            jax.ShapeDtypeStruct((Nq, kk), jnp.int32),
+            jax.ShapeDtypeStruct((Nq, 1, kk), jnp.float32),
+            jax.ShapeDtypeStruct((Nq, 1, kk), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(probes, qp, g, gn, ids)

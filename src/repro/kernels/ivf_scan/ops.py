@@ -10,8 +10,9 @@ here, mirroring kernels/pq_adc/ops.py:
   * XLA fallback: the ref oracle chunked over ``block_q`` query rows
     (lax.map keeps the gathered (block_q, nprobe, cap, k) intermediate
     cache-sized — the chunking serve/ivf.py always used);
-  * kernel dispatch: lane-pad the projected dim, flatten segments,
-    pick a tile dividing cap, run the fused kernel, mask BIG-sentinel
+  * kernel dispatch: pick a tile dividing cap, lay the segments out as
+    the kernel takes them (per-query (Nq, 1, k) rows, one (1, bM) row of
+    norms and ids per tile), run the fused kernel, mask BIG-sentinel
     survivors to id -1, and apply the final (distance, id) sort.
 """
 
@@ -20,9 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._dispatch import (LANE, default_interpret,
-                                     map_query_chunks, pad_axis, round_up,
-                                     segment_block)
+from repro.kernels._dispatch import map_query_chunks, segment_block
 from repro.kernels.metric_topk.kernel import BIG
 from repro.kernels.ivf_scan.kernel import ivf_scan_topk_fused
 from repro.kernels.ivf_scan.ref import ivf_scan_topk_ref
@@ -63,14 +62,15 @@ def ivf_scan_topk(qp, probes, g, gn, ids, *, kk: int, block_q: int = 16,
             lambda q, pr: ivf_scan_topk_ref(q, pr, g, gn, ids, kk),
             (qp, probes), block_q)
 
-    kP = round_up(k, LANE)      # zero pad columns are distance-neutral
-    qp_pad = pad_axis(qp.astype(jnp.float32), kP, 1)
-    g_pad = pad_axis(g.reshape(C * cap, k).astype(jnp.float32), kP, 1)
+    # blocks span k whole (legal at any width), so the segments are
+    # never copied to lane-pad it
     bM = segment_block(cap, block_m)
     d, i = ivf_scan_topk_fused(
-        probes.astype(jnp.int32), qp_pad, g_pad, gn.reshape(C * cap),
-        ids.reshape(C * cap), cap=cap, kk=kk, block_m=bM,
-        interpret=default_interpret(interpret))
+        probes.astype(jnp.int32), qp.astype(jnp.float32)[:, None, :],
+        g.reshape(C * cap, k).astype(jnp.float32),
+        gn.reshape(-1, 1, bM), ids.reshape(-1, 1, bM), cap=cap, kk=kk,
+        block_m=bM, interpret=interpret)
+    d, i = d[:, 0, :], i[:, 0, :]
     # BIG-sentinel survivors are pad slots; the streaming merge may have
     # parked a knocked-out winner's id there — the reference reports -1
     i = jnp.where(d >= BIG, -1, i)

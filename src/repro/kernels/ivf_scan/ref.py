@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels._dispatch import topk_by_distance
+from repro.kernels._dispatch import HIGHEST, topk_by_distance
 
 
 def ivf_scan_topk_ref(qp, probes, g, gn, ids, kk: int):
@@ -43,7 +43,7 @@ def ivf_scan_topk_ref(qp, probes, g, gn, ids, kk: int):
     gng = jnp.take(gn, probes, axis=0, mode="clip")  # (Nq, np, cap)
     idg = jnp.take(ids, probes, axis=0, mode="clip")
     qn = jnp.sum(jnp.square(qp), axis=1)
-    cross = jnp.einsum("qpck,qk->qpc", gg, qp)
+    cross = jnp.einsum("qpck,qk->qpc", gg, qp, precision=HIGHEST)
     d = jnp.maximum(qn[:, None, None] + gng - 2.0 * cross, 0.0)
     Nq = qp.shape[0]
     return topk_by_distance(d.reshape(Nq, -1), idg.reshape(Nq, -1), kk)

@@ -1,30 +1,35 @@
-"""Pallas TPU kernel: fused metric-space top-k retrieval (query side).
+"""Pallas TPU kernel: fused metric-space distance + streaming top-k.
 
-The serving hot path: given raw queries ``q`` (Nq, d), the learned metric
-factor ``L`` (k, d), and a gallery that was pre-projected **once** at index
-build time (``gp = G @ L^T`` (M, k), ``gn = ||gp||^2`` (M,)), compute per
-query the k_top nearest gallery rows under the Mahalanobis metric
-``M = L^T L`` — in one pass, without ever materializing the (Nq, M)
-distance matrix in HBM:
+The serving hot path: given queries already projected into the metric
+space (``qp = q @ L^T`` (Nq, k), one XLA matmul in ops.py) and a gallery
+that was pre-projected **once** at index build time (``gp = G @ L^T``
+(M, k), ``gn = ||gp||^2`` (1, M)), compute per query the k_top nearest
+gallery rows under the Mahalanobis metric ``M = L^T L`` — in one pass,
+without ever materializing the (Nq, M) distance matrix in HBM:
 
-    qp       = q @ L^T                       (MXU, once per query tile,
-                                              kept in VMEM scratch)
     D[:, j]  = ||qp||^2 + gn_j - 2 qp . gp_j (per (bQ, bM) gallery tile)
     best     = stream-merge(best, D tile)    (running top-k in VMEM)
 
-Grid: (Nq/bQ, M/bM) — gallery innermost, so the projected-query tile and the
-running (bQ, k_top) best-distance/best-index buffers live in VMEM scratch
-across the whole gallery sweep; outputs are written on the last gallery
-step. The merge is k_top rounds of (min, argmin, one-hot mask) over the
-(bQ, k_top + bM) candidate row — pure VPU ops, no sort network — which is
-cheap because k_top << bM.
+The query projection stays outside the kernel: at the paper's widths L
+is (1000, 21,504) f32, 86 MB, far more than VMEM holds, and sharing the
+XLA projection with the reference path keeps the two paths' qp
+identical.
+
+Grid: (Nq/bQ, M/bM) — gallery innermost, so the query tile and the
+running (bQ, k_top) best-distance/best-index buffers live in VMEM across
+the whole gallery sweep; outputs are written on the last gallery step.
+The merge is k_top rounds of (min, argmin, one-hot mask) over the
+(bQ, k_top + bM) candidate row — pure VPU ops, no sort network — which
+is cheap because k_top << bM. Row norms ride in as a (1, M) lane-major
+row so each (1, bM) tile broadcasts straight against the (bQ, bM)
+cross term.
 
 Tie-breaking matches ``jax.lax.top_k``: equal distances resolve to the
-smaller gallery index (earlier tiles sit first in the candidate row; within
-a tile the index iota ascends; argmin takes the first minimum).
+smaller gallery index (earlier tiles sit first in the candidate row;
+within a tile the index iota ascends; argmin takes the first minimum).
 
-ops.py pads d/k to 128-lane multiples and gallery rows to the tile with
-``gn = +BIG`` sentinels, so padded rows can never enter the top-k.
+ops.py pads gallery rows to the tile with ``gn = +BIG`` sentinels, so
+padded rows can never enter the top-k; blocks span k whole.
 """
 
 from __future__ import annotations
@@ -35,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels._dispatch import HIGHEST, default_interpret
 
 # Sentinel "infinite" distance for padded gallery rows / best-buffer init.
 # Large enough to lose to any real squared distance, small enough that
@@ -62,29 +69,23 @@ def _merge_topk(bd, bi, d, gidx, k_top: int):
     return jnp.stack(new_d, axis=1), jnp.stack(new_i, axis=1)
 
 
-def _metric_topk_kernel(q_ref, L_ref, gp_ref, gn_ref,
+def _metric_topk_kernel(qp_ref, gp_ref, gn_ref,
                         od_ref, oi_ref,
-                        qp_ref, bd_ref, bi_ref,
+                        bd_ref, bi_ref,
                         *, k_top: int, nm: int, block_m: int):
     mi = pl.program_id(1)
 
     @pl.when(mi == 0)
-    def _project_and_reset():
-        # query projection fused into the same pass — computed once per
-        # query tile, reused for every gallery tile from VMEM
-        qp_ref[...] = jax.lax.dot_general(
-            q_ref[...].astype(jnp.float32), L_ref[...].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    def _reset():
         bd_ref[...] = jnp.full(bd_ref.shape, BIG, jnp.float32)
         bi_ref[...] = jnp.zeros(bi_ref.shape, jnp.int32)
 
     qp = qp_ref[...]                                     # (bQ, k)
-    qn = jnp.sum(jnp.square(qp), axis=1)                 # (bQ,)
+    qn = jnp.sum(jnp.square(qp), axis=1, keepdims=True)  # (bQ, 1)
     cross = jax.lax.dot_general(
-        qp, gp_ref[...].astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    d = qn[:, None] + gn_ref[...][None, :] - 2.0 * cross
-    d = jnp.maximum(d, 0.0)                              # (bQ, bM)
+        qp, gp_ref[...], (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
+    d = jnp.maximum(qn + gn_ref[...] - 2.0 * cross, 0.0)  # (bQ, bM)
     gidx = (mi * block_m
             + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1))
 
@@ -100,25 +101,26 @@ def _metric_topk_kernel(q_ref, L_ref, gp_ref, gn_ref,
 
 @functools.partial(jax.jit, static_argnames=("k_top", "block_q", "block_m",
                                              "interpret"))
-def metric_topk_fused(q, L, gp, gn, *, k_top: int = 10,
-                      block_q: int = 128, block_m: int = 512,
-                      interpret: bool = True):
-    """Fused project + distance + streaming top-k.
+def metric_topk_fused(qp, gp, gn, *, k_top: int = 10,
+                      block_q: int = 128, block_m: int = 1024,
+                      interpret=None):
+    """Fused distance + streaming top-k over a pre-projected gallery.
 
     Args:
-      q:  (Nq, d) raw queries.
-      L:  (k, d) metric factor (held whole in VMEM — serving-sized k*d).
-      gp: (M, k) pre-projected gallery rows.
-      gn: (M,) squared norms of gp rows (+BIG for padded rows).
+      qp: (Nq, k) f32 projected queries.
+      gp: (M, k) f32 pre-projected gallery rows.
+      gn: (1, M) f32 squared norms of gp rows (+BIG for padded rows).
+      interpret: None compiles on TPU and interprets elsewhere.
 
     Shapes must tile evenly (ops.py pads otherwise): Nq % block_q == 0 and
     M % block_m == 0. Returns (dists (Nq, k_top) f32 ascending,
     indices (Nq, k_top) int32).
     """
-    Nq, d = q.shape
-    M, k = gp.shape
+    Nq, k = qp.shape
+    M = gp.shape[0]
     bQ, bM = min(block_q, Nq), min(block_m, M)
     assert Nq % bQ == 0 and M % bM == 0, (Nq, M, bQ, bM)
+    assert gn.shape == (1, M), (gn.shape, M)
     assert k_top <= M, (k_top, M)
     nm = M // bM
 
@@ -128,10 +130,9 @@ def metric_topk_fused(q, L, gp, gn, *, k_top: int = 10,
         kernel,
         grid=(Nq // bQ, nm),
         in_specs=[
-            pl.BlockSpec((bQ, d), lambda i, j: (i, 0)),     # q
-            pl.BlockSpec((k, d), lambda i, j: (0, 0)),      # L (whole)
+            pl.BlockSpec((bQ, k), lambda i, j: (i, 0)),     # qp
             pl.BlockSpec((bM, k), lambda i, j: (j, 0)),     # gp
-            pl.BlockSpec((bM,), lambda i, j: (j,)),         # gn
+            pl.BlockSpec((1, bM), lambda i, j: (0, j)),     # gn
         ],
         out_specs=[
             pl.BlockSpec((bQ, k_top), lambda i, j: (i, 0)),
@@ -142,9 +143,8 @@ def metric_topk_fused(q, L, gp, gn, *, k_top: int = 10,
             jax.ShapeDtypeStruct((Nq, k_top), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bQ, k), jnp.float32),       # projected query tile
             pltpu.VMEM((bQ, k_top), jnp.float32),   # running best distances
             pltpu.VMEM((bQ, k_top), jnp.int32),     # running best indices
         ],
-        interpret=interpret,
-    )(q, L, gp, gn)
+        interpret=default_interpret(interpret),
+    )(qp, gp, gn)

@@ -4,17 +4,21 @@ The serving contract (serve/index.py builds on this):
 
   * ``project_gallery``  — the once-per-index amortization: gp = G @ L^T and
     its row norms. Everything at query time is O(k)-dimensional.
-  * ``metric_topk``      — padded dispatch into the Pallas kernel
+  * ``metric_topk``      — projects the queries (one XLA matmul), then
+    padded dispatch into the Pallas scan kernel
     (kernel.py); ``use_kernel=False`` routes to the factored XLA path
     instead (there is no automatic shape-based fallback — padding makes
     every shape kernel-tileable).
   * ``metric_topk_xla``  — the factored pure-XLA fast path (also the
     per-shard body inside serve/index.py's shard_map).
 
-Padding rules: feature dim d and projection dim k pad with zeros to
-128-lane multiples (zero columns change no distance); query rows pad to the
-query tile (outputs sliced back); gallery rows pad to the gallery tile with
-``gn = +BIG`` sentinels so they can never enter the top-k.
+Both paths share the query projection (default precision) and score
+at full f32 precision (docs/kernels.md "Precision on the TPU").
+
+Padding rules: query rows pad to the query tile (outputs sliced back);
+gallery rows pad to the gallery tile with ``gn = +BIG`` sentinels so
+they can never enter the top-k. The projection dim k is never padded:
+the kernel's blocks span it whole.
 """
 
 from __future__ import annotations
@@ -25,8 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels._dispatch import (LANE, SUBLANE, check_metric_factor,
-                                     default_interpret, pad_axis,
-                                     pick_block, round_up)
+                                     matmul_t, pad_axis, pick_block,
+                                     round_up)
 from repro.kernels.metric_topk.kernel import BIG, metric_topk_fused
 from repro.kernels.metric_topk.ref import metric_topk_ref
 
@@ -39,7 +43,7 @@ def project_gallery(L, gallery):
     (d_out, d_in) — square or rectangular — and gp is sized d_out.
     """
     check_metric_factor(L, jnp.shape(gallery)[-1])
-    gp = gallery.astype(jnp.float32) @ L.astype(jnp.float32).T
+    gp = matmul_t(gallery, L)
     gn = jnp.sum(jnp.square(gp), axis=1)
     return gp, gn
 
@@ -48,12 +52,11 @@ def project_gallery(L, gallery):
 def metric_topk_xla(L, queries, gp, gn, k_top: int):
     """Factored XLA path: project queries, reuse precomputed gallery norms,
     lax.top_k. Production path on hosts without a Pallas backend."""
-    qp = queries.astype(jnp.float32) @ L.astype(jnp.float32).T
-    return metric_topk_ref(qp, gp, k_top, gn)
+    return metric_topk_ref(matmul_t(queries, L), gp, k_top, gn)
 
 
 def metric_topk(L, queries, gp, gn=None, *, k_top: int = 10,
-                block_q: int = 128, block_m: int = 512,
+                block_q: int = 128, block_m: int = 1024,
                 use_kernel: bool = True, interpret=None):
     """Top-k gallery neighbors of raw queries under the metric L^T L.
 
@@ -62,12 +65,12 @@ def metric_topk(L, queries, gp, gn=None, *, k_top: int = 10,
       queries: (Nq, d_in) raw queries.
       gp: (M, d_out) pre-projected gallery (see project_gallery).
       gn: optional (M,) precomputed gp row norms.
+      block_q / block_m: kernel query / gallery row tiles.
       interpret: None (default) compiles the kernel on TPU and interprets
         elsewhere; pass a bool to force.
 
     Returns (dists (Nq, k_top) f32 ascending, indices (Nq, k_top) int32).
     """
-    interpret = default_interpret(interpret)
     Nq, d = queries.shape
     check_metric_factor(L, d)
     M, k = gp.shape
@@ -78,20 +81,17 @@ def metric_topk(L, queries, gp, gn=None, *, k_top: int = 10,
     if not use_kernel:
         return metric_topk_xla(L, queries, gp, gn, k_top)
 
-    # lane-align the contracted dims (zero pads are distance-neutral)
-    dP, kP = round_up(d, LANE), round_up(k, LANE)
-    qpad = pad_axis(queries.astype(jnp.float32), dP, 1)
-    Lpad = pad_axis(pad_axis(L.astype(jnp.float32), dP, 1), kP, 0)
-    gpad = pad_axis(gp.astype(jnp.float32), kP, 1)
-
     # row tiles: queries sliced back after, gallery padded with BIG norms
+    # (a copy of gp per call unless M is a multiple of the tile). The
+    # projected dim stays whole: a block spanning a full dim is legal at
+    # any width, so gp is never copied to lane-pad it.
     bQ = pick_block(Nq, block_q, SUBLANE)
     bM = pick_block(M, block_m, LANE)
-    qpad = pad_axis(qpad, round_up(Nq, bQ), 0)
-    gpad = pad_axis(gpad, round_up(M, bM), 0)
+    qpad = pad_axis(matmul_t(queries, L), round_up(Nq, bQ), 0)
+    gpad = pad_axis(gp.astype(jnp.float32), round_up(M, bM), 0)
     gnpad = pad_axis(gn.astype(jnp.float32), round_up(M, bM), 0, value=BIG)
 
-    dists, idxs = metric_topk_fused(qpad, Lpad, gpad, gnpad, k_top=k_top,
-                                    block_q=bQ, block_m=bM,
+    dists, idxs = metric_topk_fused(qpad, gpad, gnpad[None, :],
+                                    k_top=k_top, block_q=bQ, block_m=bM,
                                     interpret=interpret)
     return dists[:Nq], idxs[:Nq]

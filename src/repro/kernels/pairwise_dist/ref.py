@@ -4,6 +4,9 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels._dispatch import (HIGHEST, LANE, matmul_t, pad_axis,
+                                     round_up)
+
 
 def pairwise_sqdist_ref(xp, yp, yn=None):
     """xp (N,k), yp (M,k) projected points (L @ x). Returns (N,M) f32:
@@ -14,5 +17,10 @@ def pairwise_sqdist_ref(xp, yp, yn=None):
     xn = jnp.sum(jnp.square(xp), axis=1)
     if yn is None:
         yn = jnp.sum(jnp.square(yp), axis=1)
-    cross = xp @ yp.T
+    # XLA's CPU dot picks its summation order by matrix shape, so the
+    # y rows go in lane-padded: a row's distance then does not depend on
+    # how many rows share the call (a gallery before and after compaction
+    # scores its rows bit-identically). No-op when M is a lane multiple.
+    M = yp.shape[0]
+    cross = matmul_t(xp, pad_axis(yp, round_up(M, LANE), 0), HIGHEST)[:, :M]
     return jnp.maximum(xn[:, None] + yn[None, :] - 2.0 * cross, 0.0)

@@ -11,7 +11,9 @@ chores the kernel contract forbids inside kernel.py:
     (block_q, nprobe, cap, S) intermediate stays cache-sized (the same
     chunking serve/pq.py always used);
   * **kernel dispatch** — flatten segments, lane-pad the LUTs, pick a
-    code tile that divides cap, run the fused kernel, then mask
+    code tile that divides cap, lay the arrays out as the kernel takes
+    them (per-query (Nq, 1, ·) LUT rows, one (1, bM) row of t and ids
+    per tile), run the fused kernel, then mask
     BIG-sentinel survivors to id -1 and apply the final (distance, id)
     sort so both paths return byte-identical arrays.
 
@@ -24,9 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._dispatch import (LANE, default_interpret,
-                                     map_query_chunks, pad_axis, round_up,
-                                     segment_block)
+from repro.kernels._dispatch import (LANE, map_query_chunks, pad_axis,
+                                     round_up, segment_block)
 from repro.kernels.metric_topk.kernel import BIG
 from repro.kernels.pq_adc.kernel import pq_adc_topk_fused
 from repro.kernels.pq_adc.ref import pq_adc_topk_ref
@@ -70,10 +71,11 @@ def pq_adc_topk(tables, dc, probes, codes, t, ids, *, kk: int,
     bM = segment_block(cap, block_m)
     tab_pad = pad_axis(tables, round_up(tables.shape[1], LANE), 1)
     d, i = pq_adc_topk_fused(
-        probes.astype(jnp.int32), tab_pad, dc,
-        codes.reshape(C * cap, S), t.reshape(C * cap),
-        ids.reshape(C * cap), n_codes=K, cap=cap, kk=kk, block_m=bM,
-        interpret=default_interpret(interpret))
+        probes.astype(jnp.int32), tab_pad[:, None, :],
+        dc.astype(jnp.float32), codes.reshape(C * cap, S),
+        t.reshape(-1, 1, bM), ids.reshape(-1, 1, bM), n_codes=K, cap=cap,
+        kk=kk, block_m=bM, interpret=interpret)
+    d, i = d[:, 0, :], i[:, 0, :]
     # entries still at the BIG sentinel are pad slots (real rows cannot
     # reach 1e30) — but the streaming merge may have parked a
     # knocked-out winner's id there; the reference always reports -1

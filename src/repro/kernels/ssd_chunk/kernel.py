@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._dispatch import default_interpret
+
 
 def _ssd_kernel(xs_ref, B_ref, C_ref, dt_ref, la_ref, y_ref, hout_ref,
                 h_ref, *, nc: int, chunk: int):
@@ -77,7 +79,7 @@ def _ssd_kernel(xs_ref, B_ref, C_ref, dt_ref, la_ref, y_ref, hout_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(xs, Bm, Cm, dt, la, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(xs, Bm, Cm, dt, la, *, chunk: int = 128, interpret=None):
     """Pane-parallel SSD scan. Shapes per ref.py: xs (G,T,p), Bm/Cm (G,T,n),
     dt/la (G,T). Returns (y (G,T,p), h_final (G,p,n))."""
     G, T, p = xs.shape
@@ -106,6 +108,6 @@ def ssd_scan(xs, Bm, Cm, dt, la, *, chunk: int = 128, interpret: bool = True):
             jax.ShapeDtypeStruct((G, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(xs, Bm, Cm, dt, la)
     return y, hf

@@ -13,7 +13,7 @@ from repro.kernels.ssd_chunk.kernel import ssd_scan
 from repro.kernels.ssd_chunk.ref import ssd_scan_ref
 
 
-def ssd_core(xs, Bm, Cm, dt, la, *, chunk: int = 128, interpret: bool = True,
+def ssd_core(xs, Bm, Cm, dt, la, *, chunk: int = 128, interpret=None,
              use_kernel: bool = True):
     """xs (B,T,H,p); Bm/Cm (B,T,n) shared across heads (mamba2 ngroups=1);
     dt/la (B,T,H). Returns (y (B,T,H,p), h_final (B,H,p,n))."""

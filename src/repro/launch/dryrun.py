@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config, list_configs, get_shape, SHAPES  # noqa: E402
 from repro.configs.base import RunConfig  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch import hlo_analysis, mesh as mesh_lib, steps  # noqa: E402
 from repro.models.transformer import build_model  # noqa: E402
 
@@ -262,6 +263,7 @@ def main():
     ap.add_argument("--skip-done", action="store_true")
     ap.add_argument("--no-hlo", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     path = _artifact_path(args.multi_pod)
     records = _load(path)

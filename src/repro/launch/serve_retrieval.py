@@ -46,9 +46,11 @@ load controller's hysteresis. The run then reports per-class
 counters/latency percentiles and the degradation transitions alongside
 the usual engine stats.
 
-With --data > 1 the gallery shards over a forced-host-device mesh
-(dry-run style) to exercise the sharded query path (both index kinds;
-incompatible with --mutable / --snapshot-dir, which are single-shard).
+With --data > 1 the gallery shards over the first N devices of the
+default backend to exercise the sharded query path (exact and IVF;
+incompatible with --mutable / --snapshot-dir, which are single-shard):
+the real chips where there are at least N, else N forced host devices
+on the CPU.
 
 Observability: ``--metrics-out FILE`` writes the run's final
 MetricsRegistry snapshot (render it with ``launch/metrics_report.py``),
@@ -126,8 +128,9 @@ def main():
                          "engine (shares its jit cache and stats) and "
                          "report yield + mining QPS")
     ap.add_argument("--data", type=int, default=1,
-                    help=">1 forces that many host devices and shards "
-                         "the gallery over the data axis")
+                    help=">1 shards the gallery over the data axis of "
+                         "that many devices (chips when present, else "
+                         "forced host devices)")
     ap.add_argument("--scheduler", action="store_true",
                     help="serve through the traffic-shaped "
                          "RequestScheduler (priority classes, deadlines, "
@@ -196,7 +199,8 @@ def main():
     if args.churn and not args.mutable:
         ap.error("--churn requires --mutable")
 
-    if args.data > 1:   # must precede first jax import
+    if args.data > 1:   # must precede first jax import; it only sizes the
+                        # CPU platform, so chips still win where present
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.data} "
             + os.environ.get("XLA_FLAGS", ""))
@@ -205,6 +209,8 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core import dml
     from repro.core.ps.trainer import train_dml_single
     from repro.data import pairs as pairdata
@@ -234,7 +240,14 @@ def main():
         L = dml.init_params(dcfg, jax.random.PRNGKey(0))
 
     # --- serving stack ---------------------------------------------------
-    mesh = make_local_mesh(data=args.data) if args.data > 1 else None
+    mesh = None
+    if args.data > 1:
+        if jax.device_count() < args.data:
+            ap.error(f"--data {args.data}: the {jax.default_backend()} "
+                     f"backend has {jax.device_count()} device(s)")
+        mesh = make_local_mesh(data=args.data)
+        print(f"gallery mesh {dict(mesh.shape)} over "
+              f"{mesh.devices.flat[0].platform} devices")
     ivf_kw = dict(n_clusters=args.n_clusters, nprobe=args.nprobe,
                   scan_impl=args.scan_impl)
     ivfpq_kw = dict(ivf_kw, n_subspaces=args.n_subspaces, bits=args.bits,
@@ -276,7 +289,8 @@ def main():
     if isinstance(ivf, (IVFIndex, IVFPQIndex)):
         from repro.serve import scan as scanmod
         scanned = ivf.nprobe * ivf.cap
-        resolved = scanmod.resolve_scan_impl(ivf.scan_impl)
+        resolved = scanmod.resolve_scan_impl(ivf.scan_impl,
+                                             sharded=ivf.n_shards > 1)
         print(f"  {type(ivf).__name__}: {ivf.n_clusters} clusters, cap "
               f"{ivf.cap}, nprobe {ivf.nprobe} -> <= {scanned} of "
               f"{ivf.size} rows scanned per query "

@@ -23,6 +23,7 @@ from repro.configs import get_config, reduced as reduce_cfg
 from repro.configs.base import RunConfig
 from repro.data.tokens import token_stream
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 
 
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--ckpt", type=str, default="")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -69,6 +69,9 @@ def main():
                          "comparison")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core import dml, eval_tasks
     from repro.core.ps import sync
     from repro.core.ps.trainer import (DMLTrainConfig,

@@ -221,7 +221,7 @@ def decode_step(p, x, cache: MambaCache, cfg: ArchConfig):
 
 
 def apply_mamba2_kernel(p, x, cfg: ArchConfig, chunk: int = 128,
-                        interpret: bool = True):
+                        interpret=None):
     """Inference/prefill forward through the Pallas SSD kernel
     (kernels/ssd_chunk): chunk tiles stay in VMEM, HBM traffic is inputs +
     outputs only. Forward-only (training uses apply_mamba2)."""

@@ -26,7 +26,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import common
-from repro.sharding.partition import shard_map
 
 
 def init_moe(cfg: ArchConfig, rng) -> dict:
@@ -196,10 +195,10 @@ def apply_moe(p, x, cfg: ArchConfig, mesh: Optional[Mesh] = None,
                  "w_down": P(expert_axis)}
     pspec = {"router": P(), **wspec}
     xspec = P(batch_axes if batch_axes else None)
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(pspec, xspec),
-                   out_specs=(xspec, P()),
-                   check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(pspec, xspec),
+                       out_specs=(xspec, P()),
+                       check_vma=False)
     return fn(p, x)
 
 

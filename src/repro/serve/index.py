@@ -183,11 +183,12 @@ class ExactIndex:
                                         (self.gp, self.gn),
                                         local_candidates, k_top)
 
+        # the gallery rides in as arguments, never as jit constants
         @jax.jit
-        def run(queries):
-            return inner(scan.project_queries(self.L, queries))
+        def run(queries, L, gp, gn):
+            return inner(scan.project_queries(L, queries), gp, gn)
 
-        return run
+        return lambda queries: run(queries, self.L, self.gp, self.gn)
 
 
 # Back-compat: PR 1 shipped the exact backend under this name.

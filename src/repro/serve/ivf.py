@@ -313,9 +313,10 @@ class IVFIndex:
             setting; ``n_clusters`` scans everything = exact).
           scan_impl: segment-scan implementation for this call — "auto" /
             "xla" / "pallas" (defaults to the build setting; see
-            scan.resolve_scan_impl). "pallas" requires a single-shard
-            index; ids match the xla path exactly, distances to f32
-            rounding.
+            scan.resolve_scan_impl — "auto" is the XLA scan on a sharded
+            index). "pallas" requires a single-shard index; ids match the
+            xla path except where two rows' distances lie within f32
+            rounding of each other and swap, distances to f32 rounding.
 
         Returns (dists (Nq, k_top) f32 ascending, global row indices
         (Nq, k_top) int32); -1 ids mark under-filled probes (raise
@@ -336,7 +337,8 @@ class IVFIndex:
             raise ValueError(
                 f"k_top={k_top} > nprobe*cap={np_ * self.cap} scanned "
                 f"rows per query; raise nprobe")
-        impl = scan.resolve_scan_impl(self.scan_impl, scan_impl)
+        impl = scan.resolve_scan_impl(self.scan_impl, scan_impl,
+                                      sharded=self.n_shards > 1)
         if impl == "pallas" and self.n_shards > 1:
             raise NotImplementedError(
                 "scan_impl='pallas' is single-shard only (the fused "
@@ -354,19 +356,21 @@ class IVFIndex:
     def _build_topk(self, k_top: int, nprobe: int, impl: str):
         C, cap = self.n_clusters, self.cap
         k = self.centroids.shape[1]
-        g = self.gp_pad.reshape(C, cap, k)
-        gn = self.gn_pad.reshape(C, cap)
-        ids = self.ids_pad.reshape(C, cap)
 
+        # the segments ride in as arguments: a jit that closed over them
+        # would bake the whole gallery into the program as a constant
         @jax.jit
-        def run(queries):
-            qp = scan.project_queries(self.L, queries)
-            probes = self._probe(qp, nprobe)
-            return ivf_scan_topk(qp, probes, g, gn, ids, kk=k_top,
+        def run(queries, L, centroids, gp_pad, gn_pad, ids_pad):
+            qp = scan.project_queries(L, queries)
+            probes = _probe(qp, centroids, nprobe)
+            return ivf_scan_topk(qp, probes, gp_pad.reshape(C, cap, k),
+                                 gn_pad.reshape(C, cap),
+                                 ids_pad.reshape(C, cap), kk=k_top,
                                  block_q=self.block_q,
                                  use_kernel=(impl == "pallas"))
 
-        return run
+        return lambda queries: run(queries, self.L, self.centroids,
+                                   self.gp_pad, self.gn_pad, self.ids_pad)
 
     # -- sharded query path (whole clusters per shard) -----------------------
 
@@ -395,22 +399,23 @@ class IVFIndex:
             slot = jnp.where((slot >= 0) & (slot < C_loc), slot, C_loc)
             return _probed_topk(qp, slot, g, gn, ids, kk, self.block_q)
 
-        inner = scan.build_sharded_topk(
-            self.mesh, self.axes, (self.gp_pad, self.gn_pad, self.ids_pad),
-            local_candidates, k_top, n_extras=1)
+        arrays = (self.gp_pad, self.gn_pad, self.ids_pad)
+        inner = scan.build_sharded_topk(self.mesh, self.axes, arrays,
+                                        local_candidates, k_top, n_extras=1)
 
         @jax.jit
-        def run(queries):
-            qp = scan.project_queries(self.L, queries)
-            return inner(qp, self._probe(qp, nprobe))
+        def run(queries, L, centroids, *arrays):
+            qp = scan.project_queries(L, queries)
+            return inner(qp, _probe(qp, centroids, nprobe), *arrays)
 
-        return run
+        return lambda queries: run(queries, self.L, self.centroids, *arrays)
 
-    def _probe(self, qp, nprobe: int):
-        """Coarse quantizer: ids of the nprobe nearest centroids (Nq, np)."""
-        cd = metric_sqdist_factored(qp, self.centroids)
-        _, probes = jax.lax.top_k(-cd, nprobe)
-        return probes.astype(jnp.int32)
+
+def _probe(qp, centroids, nprobe: int):
+    """Coarse quantizer: ids of the nprobe nearest centroids (Nq, np)."""
+    cd = metric_sqdist_factored(qp, centroids)
+    _, probes = jax.lax.top_k(-cd, nprobe)
+    return probes.astype(jnp.int32)
 
 
 def _probed_topk(qp, cluster_slots, g, gn, ids, kk: int, block_q: int):

@@ -508,20 +508,22 @@ class IVFPQIndex:
         """
         C, cap = self.n_clusters, self.cap
         S, K = self.pq.n_subspaces, self.pq.n_codes
-        codes = self.codes_pad.reshape(C, cap, S)
-        t = self.t_pad.reshape(C, cap)
-        ids = self.ids_pad.reshape(C, cap)
         block_q = self.block_q
         kk = max(k_top, rr)
-        gp_dev, gn_dev = self._device_store() if fused else (None, None)
+        store = self._device_store() if fused else (None, None)
 
+        # index arrays ride in as arguments: a jit that closed over them
+        # would bake the codes and the device store into the program as
+        # constants
         @jax.jit
-        def run(queries):
-            qp = scan.project_queries(self.L, queries)
-            cd = metric_sqdist_factored(qp, self.centroids)
+        def run(queries, L, centroids, codes, t, ids, gp_dev, gn_dev):
+            qp = scan.project_queries(L, queries)
+            cd = metric_sqdist_factored(qp, centroids)
             neg, probes = jax.lax.top_k(-cd, nprobe)
             tables = self.pq.ip_tables(qp).reshape(qp.shape[0], S * K)
-            d, i = pq_adc_topk(tables, -neg, probes, codes, t, ids,
+            d, i = pq_adc_topk(tables, -neg, probes,
+                               codes.reshape(C, cap, S),
+                               t.reshape(C, cap), ids.reshape(C, cap),
                                kk=kk, block_q=block_q,
                                use_kernel=(impl == "pallas"))
             if not fused:
@@ -534,7 +536,9 @@ class IVFPQIndex:
             norms = jnp.where(i >= 0, jnp.take(gn_dev, safe, axis=0), BIG)
             return _exact_rerank(qp, rows, norms, i, k_top)
 
-        return run
+        return lambda queries: run(queries, self.L, self.centroids,
+                                   self.codes_pad, self.t_pad, self.ids_pad,
+                                   *store)
 
     # -- host-store exact re-rank --------------------------------------------
 

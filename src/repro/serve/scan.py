@@ -39,9 +39,10 @@ def project_queries(L, queries):
     ``L`` is the (d_out, d_in) metric factor — square or rectangular.
     Validates the factor contract up front (shapes are static at trace
     time, so this also fires with a clear error from inside jit instead
-    of an opaque dot-dimension failure)."""
+    of an opaque dot-dimension failure). The kernel and XLA scan paths
+    share this projection (_dispatch.matmul_t)."""
     check_metric_factor(L, jnp.shape(queries)[-1])
-    return queries.astype(jnp.float32) @ L.astype(jnp.float32).T
+    return _dispatch.matmul_t(queries, L)
 
 
 def check_metric_factor(L, d_in=None, *, what: str = "L"):
@@ -55,7 +56,8 @@ def check_metric_factor(L, d_in=None, *, what: str = "L"):
 SCAN_IMPLS = ("auto", "xla", "pallas")
 
 
-def resolve_scan_impl(default: str, override=None) -> str:
+def resolve_scan_impl(default: str, override=None, *,
+                      sharded: bool = False) -> str:
     """Resolve a segment-scan implementation knob to "xla" or "pallas".
 
     ``default`` is the index's build-time setting; ``override`` a
@@ -64,13 +66,17 @@ def resolve_scan_impl(default: str, override=None) -> str:
     remapping, the k_top=0 bug class). "auto" picks the fused Pallas
     kernel when the runtime backend is a TPU and the XLA path elsewhere
     (interpret-mode Pallas is a correctness tool, not a serving path).
+    On a ``sharded`` index "auto" is the per-shard XLA scan: the fused
+    kernel does not compose with shard_map yet, and an explicit
+    "pallas" there is the caller's to reject.
     """
     impl = default if override is None else override
     if impl not in SCAN_IMPLS:
         raise ValueError(f"unknown scan_impl {impl!r} "
                          f"({'|'.join(SCAN_IMPLS)})")
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and not sharded else "xla"
     return impl
 
 
@@ -177,8 +183,11 @@ def build_sharded_topk(mesh: Mesh, axes: Tuple[str, ...],
     (distance, id) merge over the concatenated (Nq, kk * n_shards)
     candidates is exact.
 
-    Returns ``run(qp, *extras) -> (dists, ids)`` (not jitted; callers wrap
-    it together with query projection).
+    Returns ``run(qp, *extras, *sharded_arrays) -> (dists, ids)`` (not
+    jitted; callers wrap it together with query projection). The arrays
+    here fix only the specs: callers pass them again per call, as
+    arguments of their jit — a jit that closed over a gallery-sized
+    array would bake it into the program as a constant.
     """
     row_ax = row_axis(axes)
     specs = tuple(P(row_ax, *([None] * (a.ndim - 1))) for a in sharded_arrays)
@@ -189,11 +198,11 @@ def build_sharded_topk(mesh: Mesh, axes: Tuple[str, ...],
         extras, locals_ = rest[:n_extras], rest[n_extras:]
         return local_candidates(shard_index(mesh, axes), qp, extras, locals_)
 
-    inner = partition.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+    inner = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs)
 
-    def run(qp, *extras):
-        cand_d, cand_i = inner(qp, *extras, *sharded_arrays)
+    def run(qp, *args):
+        cand_d, cand_i = inner(qp, *args)
         return topk_by_distance(cand_d, cand_i, k_top)
 
     return run

@@ -131,21 +131,6 @@ def constrain(x: jax.Array, logical: Sequence[Optional[str]],
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable jax.shard_map.
-
-    jax >= 0.5 exports jax.shard_map (replication check kwarg: check_vma);
-    jax 0.4.x only has jax.experimental.shard_map.shard_map (check_rep).
-    All repo call sites go through here.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def _current_mesh() -> Optional[Mesh]:
     try:
         from jax._src.mesh import thread_resources

@@ -184,3 +184,66 @@ class TestPairSampling:
         batch = next(stream)
         keys = np.asarray(batch["a"]) * 400 + np.asarray(batch["b"])
         assert len(np.unique(keys)) == len(keys)
+
+
+class TestDeviceFeatureStream:
+    CFG = pairdata.PairDatasetConfig(n_samples=0, feat_dim=96, n_classes=6,
+                                     kind="llc_like", seed=3)
+
+    def test_chunks_are_seeded_and_disjoint(self):
+        x0, y0 = pairdata.llc_like_chunk(self.CFG, 0, 64)
+        x0b, y0b = pairdata.llc_like_chunk(self.CFG, 0, 64)
+        x1, _ = pairdata.llc_like_chunk(self.CFG, 1, 64)
+        assert x0.shape == (64, 96) and x0.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(x0), np.asarray(x0b))
+        np.testing.assert_array_equal(np.asarray(y0), np.asarray(y0b))
+        assert not np.array_equal(np.asarray(x0), np.asarray(x1))
+        assert 0 <= int(y0.min()) and int(y0.max()) < 6
+
+    def test_llc_like_class_structure(self):
+        x, y = map(np.asarray, pairdata.llc_like_chunk(self.CFG, 0, 600))
+        assert (x >= 0).all()
+        # same class -> same support; the support density is 1 - sparsity
+        for c in np.unique(y):
+            nz = x[y == c] > 0
+            assert (nz == nz[0]).all()
+        assert abs((x > 0).mean() - (1 - self.CFG.sparsity)) < 0.05
+
+    def test_other_kinds_rejected(self):
+        import dataclasses
+        with pytest.raises(ValueError, match="llc_like"):
+            pairdata.llc_like_chunk(
+                dataclasses.replace(self.CFG, kind="class_blobs"), 0, 8)
+
+
+class TestIndexPairSource:
+    def test_worker_streams_partition_and_gather(self):
+        cfg = pairdata.PairDatasetConfig(n_samples=200, feat_dim=16,
+                                         n_classes=4, seed=0)
+        x, y = pairdata.make_features(cfg)
+        idx = pairdata.sample_pair_indices(y, 300, 300, seed=1)
+        src = pairdata.IndexPairSource(jnp.asarray(x), idx)
+        streams = src.worker_streams(3, 32, seed=5)
+        assert len(streams) == 3
+        b = next(streams[0])
+        assert b["xs"].shape == b["ys"].shape == (32, 16)
+        # balanced S/D and rows gathered from the feature store
+        assert int(b["sim"].sum()) == 16
+        rows = {tuple(r) for r in np.round(x, 5)}
+        assert all(tuple(r) in rows for r in np.round(np.asarray(b["xs"]), 5))
+
+    def test_trainer_accepts_source(self):
+        from repro.core.ps import sync
+        from repro.core.ps.trainer import (DMLTrainConfig,
+                                           train_dml_distributed)
+        cfg = pairdata.PairDatasetConfig(n_samples=300, feat_dim=16,
+                                         n_classes=4, seed=0)
+        x, y = pairdata.make_features(cfg)
+        src = pairdata.IndexPairSource(
+            jnp.asarray(x), pairdata.sample_pair_indices(y, 400, 400))
+        tcfg = DMLTrainConfig(dml=dml.DMLConfig(feat_dim=16, proj_dim=8),
+                              ps=sync.PSConfig(n_workers=1), batch_size=64,
+                              steps=5, lr=1e-2, log_every=1)
+        L, hist = train_dml_distributed(tcfg, src)
+        assert L.shape == (8, 16) and len(hist) == 5
+        assert np.isfinite([h["loss"] for h in hist]).all()

@@ -70,11 +70,12 @@ class TestDMLPairKernel:
         xs = jnp.asarray(rng.randn(B, d), jnp.float32)
         ys = jnp.asarray(rng.randn(B, d), jnp.float32)
         sim = jnp.asarray((rng.rand(B) < 0.5).astype(np.int32))
-        losses, d2, proj = dml_pair_fused(L, xs, ys, sim, lam=1.0, margin=1.0,
-                                          block_b=64, block_k=64, block_d=128)
+        losses, d2, proj = dml_pair_fused(L, xs, ys, sim[:, None], lam=1.0,
+                                          margin=1.0, block_b=64,
+                                          block_k=64, block_d=128)
         l_ref, d2_ref, p_ref = dml_pair_ref(L, xs, ys, sim)
-        np.testing.assert_allclose(losses, l_ref, rtol=2e-5, atol=1e-5)
-        np.testing.assert_allclose(d2, d2_ref, rtol=2e-5, atol=1e-5)
+        np.testing.assert_allclose(losses[:, 0], l_ref, rtol=2e-5, atol=1e-5)
+        np.testing.assert_allclose(d2[:, 0], d2_ref, rtol=2e-5, atol=1e-5)
         np.testing.assert_allclose(proj, p_ref, rtol=2e-5, atol=1e-5)
 
 
@@ -121,6 +122,13 @@ class TestFlashAttention:
                                    rtol=5e-2, atol=5e-2)
 
 
+def _largest_tile(n, cap=512):
+    """Largest power-of-two tile (<= cap) dividing n: a kernel-level
+    tiling sweep for interpret mode (ops.py pads to TPU-legal tiles)."""
+    return next(t for t in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+                if t <= cap and n % t == 0)
+
+
 class TestPairwiseDist:
     @pytest.mark.parametrize("N,M,k", [
         (64, 64, 32), (256, 128, 512), (128, 256, 64), (512, 512, 600),
@@ -129,12 +137,27 @@ class TestPairwiseDist:
         rng = np.random.RandomState(N + M)
         xp = jnp.asarray(rng.randn(N, k), jnp.float32)
         yp = jnp.asarray(rng.randn(M, k), jnp.float32)
-        from repro.kernels.pairwise_dist.ops import _largest_tile
-        out = pairwise_sqdist(xp, yp, block_n=_largest_tile(N),
+        xn = jnp.sum(jnp.square(xp), axis=1)[:, None]
+        yn = jnp.sum(jnp.square(yp), axis=1)[None, :]
+        out = pairwise_sqdist(xp, yp, xn, yn, block_n=_largest_tile(N),
                               block_m=_largest_tile(M),
                               block_c=_largest_tile(k))
         ref = pairwise_sqdist_ref(xp, yp)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-3)
+
+    @pytest.mark.parametrize("N,M,k", [(37, 53, 20), (8, 300, 130)])
+    def test_metric_matrix_pads_any_shape(self, N, M, k):
+        # rows off the tiles and d_out off the lane width run the kernel
+        # on padded inputs (no silent fallback to the reference)
+        rng = np.random.RandomState(N + M)
+        L = jnp.asarray(0.3 * rng.randn(k, 24), jnp.float32)
+        x = jnp.asarray(rng.randn(N, 24), jnp.float32)
+        y = jnp.asarray(rng.randn(M, 24), jnp.float32)
+        D = metric_sqdist_matrix(L, x, y)
+        ref = metric_sqdist_matrix(L, x, y, use_kernel=False)
+        assert D.shape == (N, M)
+        np.testing.assert_allclose(np.asarray(D), np.asarray(ref),
                                    rtol=1e-4, atol=1e-3)
 
     def test_metric_matrix_consistent_with_dml(self):

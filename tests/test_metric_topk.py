@@ -15,9 +15,9 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.metric_topk import (metric_topk, metric_topk_naive,
-                                       metric_topk_ref, metric_topk_xla,
-                                       project_gallery)
+from repro.kernels.metric_topk import (metric_sqdist_factored, metric_topk,
+                                       metric_topk_naive, metric_topk_ref,
+                                       metric_topk_xla, project_gallery)
 from repro.serve import (FakeClock, GalleryIndex, MicroBatcher,
                          RetrievalEngine)
 
@@ -87,6 +87,19 @@ class TestMetricTopkKernel:
         _, idx = metric_topk(L, q, gp, gn, k_top=130)
         assert np.asarray(idx).max() < 130
         assert np.asarray(idx).min() >= 0
+
+    @pytest.mark.parametrize("M,start", [(400, 0), (410, 0), (390, 20),
+                                         (130, 300)])
+    def test_xla_distances_independent_of_gallery_size(self, M, start):
+        # a row scores bit-identically whatever rows share the scan with
+        # it (what lets MutableIndex compaction keep its answers exact)
+        L, q, G = _data(9, 520, 24, 12)
+        gp, gn = project_gallery(L, G)
+        qp = q @ L.T
+        full = np.asarray(metric_sqdist_factored(qp, gp, gn))
+        part = np.asarray(metric_sqdist_factored(
+            qp, gp[start:start + M], gn[start:start + M]))
+        np.testing.assert_array_equal(part, full[:, start:start + M])
 
 
 class TestServingStack:

@@ -166,3 +166,27 @@ class TestShardingRules:
         spec16 = partition.logical_to_physical(
             ("heads",), jax.make_mesh((1,), ("model",)), shape=(9,))
         assert spec16 is not None  # smoke: callable under any mesh
+
+
+class TestLaunchHelpers:
+    def test_local_mesh_axes_are_auto(self):
+        from jax.sharding import AxisType
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh()
+        assert tuple(mesh.axis_names) == ("data", "model")
+        assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+    def test_compile_cache_dir(self, monkeypatch):
+        import os
+        from repro.launch import compile_cache
+        set_dirs = []
+        monkeypatch.setattr(compile_cache.jax.config, "update",
+                            lambda k, v: set_dirs.append((k, v)))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert set_dirs == []                 # honoured, nothing else set
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert set_dirs == [("jax_compilation_cache_dir", path)]

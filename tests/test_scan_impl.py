@@ -10,6 +10,10 @@ at every entry point instead of silently remapping them (the k_top=0
 bug class).
 """
 
+import importlib.util
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -42,6 +46,60 @@ def test_resolve_scan_impl_contract():
             resolve_scan_impl("auto", bad)
         with pytest.raises(ValueError, match="scan_impl"):
             resolve_scan_impl(bad)
+
+
+def test_auto_resolves_from_platform_and_sharding(monkeypatch):
+    import repro.serve.scan as scan
+    monkeypatch.setattr(scan.jax, "default_backend", lambda: "tpu")
+    assert resolve_scan_impl("auto") == "pallas"
+    # the fused kernel does not compose with shard_map: a sharded index's
+    # auto is the per-shard XLA scan, an explicit pallas stays pallas (the
+    # index rejects it)
+    assert resolve_scan_impl("auto", sharded=True) == "xla"
+    assert resolve_scan_impl("pallas", sharded=True) == "pallas"
+    monkeypatch.setattr(scan.jax, "default_backend", lambda: "cpu")
+    assert resolve_scan_impl("auto") == "xla"
+
+
+def _chip_smoke():
+    """The repo-root smoke script as a module: its on-chip parity check
+    (tie_mismatches) lives there, not in the serving library."""
+    if "chip_smoke" not in sys.modules:
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chip_smoke.py")
+        spec = importlib.util.spec_from_file_location("chip_smoke", path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules["chip_smoke"] = mod     # dataclasses look it up
+        spec.loader.exec_module(mod)
+    return sys.modules["chip_smoke"]
+
+
+@pytest.mark.parametrize("case", ["same", "tie_swap", "far_swap",
+                                  "boundary_tie", "boundary_far",
+                                  "wide_tol"])
+def test_tie_mismatches(case):
+    tie_mismatches = _chip_smoke().tie_mismatches
+    d = np.array([[1.0, 2.0, 3.0, 4.0]], np.float32)
+    i = np.array([[10, 11, 12, 13]])
+    d2, i2 = d.copy(), i.copy()
+    want, tol = (0, 0), np.array([4e-5])
+    if case == "tie_swap":          # two rows within f32 rounding swap
+        d[0, 1] = d[0, 2] = d2[0, 1] = d2[0, 2] = 2.5
+        i2[0, 1:3] = [12, 11]
+        want = (2, 0)
+    elif case == "far_swap":        # a real reorder is never a tie
+        i2[0, 1:3] = [12, 11]
+        want = (2, 2)
+    elif case == "boundary_tie":    # k-th row replaced by an equidistant one
+        i2[0, 3], d2[0, 3] = 99, 4.0 + 1e-6
+        want = (1, 0)
+    elif case == "boundary_far":
+        i2[0, 3], d2[0, 3] = 99, 3.5
+        want = (1, 1)
+    elif case == "wide_tol":        # the tolerance is the caller's: a
+        i2[0, 1:3] = [12, 11]       # gap of 1.0 is a tie under tol 2.0
+        want, tol = (2, 0), 2.0
+    assert tie_mismatches(i, d, i2, d2, tol)[:2] == want
 
 
 def test_ivf_pallas_matches_xla(data):

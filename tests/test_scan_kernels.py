@@ -191,7 +191,7 @@ class TestTopkContractProperty:
                         ip = np.float32(0.0)
                         for s in range(S):
                             ip = np.float32(
-                                ip + tb[q, s * K + cd[c, r, s]])
+                                ip + tb[q, s * K + int(cd[c, r, s])])
                         d = np.float32(
                             np.float32(dcn[q, j] + tn[c, r])
                             - np.float32(2.0) * ip)
