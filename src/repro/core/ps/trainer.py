@@ -26,6 +26,7 @@ from repro.core import dml, losses
 from repro.core.ps import sync
 from repro.data.loader import partition_pairs
 from repro.data.pairs import pair_batches
+from repro.obs import annotate, annotate_step
 from repro.optim import Optimizer, sgd
 
 
@@ -42,8 +43,14 @@ class DMLTrainConfig:
 def stack_worker_streams(streams) -> Iterator[dict]:
     """Zip per-worker batch streams into (P, B, ...) stacked batches."""
     while True:
-        bs = [next(s) for s in streams]
-        yield {k: jnp.stack([b[k] for b in bs]) for k in bs[0]}
+        yield _stacked([next(s) for s in streams])
+
+
+def _stacked(bs) -> dict:
+    """The ``train.stack`` profiler span; its result goes straight to the
+    stream's ``yield``, so no local holds it while the next is made."""
+    with annotate("train.stack"):
+        return {k: jnp.stack([b[k] for b in bs]) for k in bs[0]}
 
 
 def make_worker_streams(pairs, n_workers: int, batch_size: int, seed: int):
@@ -100,16 +107,26 @@ def train_dml_distributed(cfg: DMLTrainConfig, pairs,
     batches = stack_worker_streams(make_worker_streams(
         pairs, cfg.ps.n_workers, cfg.batch_size, cfg.ps.seed))
 
+    # each iteration's stages are profiler spans (``train`` over the step,
+    # ``train.batch`` / ``train.step`` / ``train.log`` within it, and the
+    # streams' ``train.draw`` / ``train.gather`` / ``train.stack``); they
+    # reach a trace only while the profiler runs
     history = []
     for t in range(cfg.steps):
-        state, metrics = step_fn(state, next(batches))
-        if t % cfg.log_every == 0 or t == cfg.steps - 1:
-            rec = {"step": t, **jax.tree.map(float, metrics)}
-            if step_hook is not None:
-                out = step_hook(t, sync.worker_mean(state.params))
-                if out is not None:
-                    rec["hook"] = out
-            history.append(rec)
+        with annotate_step("train", t):
+            with annotate("train.batch"):
+                batch = next(batches)
+            with annotate("train.step"):
+                state, metrics = step_fn(state, batch)
+            del batch           # not alive while the next one is made
+            if t % cfg.log_every == 0 or t == cfg.steps - 1:
+                with annotate("train.log"):
+                    rec = {"step": t, **jax.tree.map(float, metrics)}
+                    if step_hook is not None:
+                        out = step_hook(t, sync.worker_mean(state.params))
+                        if out is not None:
+                            rec["hook"] = out
+                    history.append(rec)
     L = sync.worker_mean(state.params)
     return L, history
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.loader import partition_pairs
+from repro.obs import annotate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,29 +226,45 @@ def distinct_draws(rng, n_pool: int, size: int) -> np.ndarray:
     return out[rng.permutation(len(out))[:size]]
 
 
+def _batch_draws(sim: np.ndarray, batch_size: int, seed: int,
+                 balanced: bool) -> Iterator[np.ndarray]:
+    """Endless per-batch pair selections over labels ``sim``: half S and
+    half D when ``balanced`` and both exist, else uniform; distinct within
+    a batch. Each draw is the trainer's ``train.draw`` profiler span."""
+    rng = np.random.RandomState(seed)
+    sim_idx = np.nonzero(sim == 1)[0]
+    dis_idx = np.nonzero(sim == 0)[0]
+    n = sim.shape[0]
+    while True:
+        with annotate("train.draw"):
+            if balanced and len(sim_idx) and len(dis_idx):
+                h = batch_size // 2
+                sel = np.concatenate([
+                    sim_idx[distinct_draws(rng, len(sim_idx), h)],
+                    dis_idx[distinct_draws(rng, len(dis_idx),
+                                            batch_size - h)]])
+            else:
+                sel = distinct_draws(rng, n, batch_size)
+        yield sel
+
+
 def pair_batches_from_indices(features: np.ndarray, idx_pairs: dict,
                               batch_size: int, seed: int = 0,
                               balanced: bool = True) -> Iterator[dict]:
     """Minibatch stream gathering features on the fly (memory-bounded).
     Constraints within a batch are distinct (no duplicated pair rows)."""
-    rng = np.random.RandomState(seed)
-    sim_idx = np.nonzero(idx_pairs["sim"] == 1)[0]
-    dis_idx = np.nonzero(idx_pairs["sim"] == 0)[0]
-    n = idx_pairs["sim"].shape[0]
-    while True:
-        if balanced and len(sim_idx) and len(dis_idx):
-            h = batch_size // 2
-            sel = np.concatenate([
-                sim_idx[distinct_draws(rng, len(sim_idx), h)],
-                dis_idx[distinct_draws(rng, len(dis_idx),
-                                        batch_size - h)]])
-        else:
-            sel = distinct_draws(rng, n, batch_size)
-        yield {
-            "xs": jnp.asarray(features[idx_pairs["a"][sel]]),
-            "ys": jnp.asarray(features[idx_pairs["b"][sel]]),
-            "sim": jnp.asarray(idx_pairs["sim"][sel]),
-        }
+    for sel in _batch_draws(idx_pairs["sim"], batch_size, seed, balanced):
+        yield _gather_indexed(features, idx_pairs, sel)
+
+
+def _gather_indexed(features, idx_pairs: dict, sel: np.ndarray) -> dict:
+    """One batch's feature gathers, the ``train.gather`` profiler span.
+    The batch goes straight to the stream's ``yield``: a generator local
+    would keep it alive while the next batch is gathered."""
+    with annotate("train.gather"):
+        return {"xs": jnp.asarray(features[idx_pairs["a"][sel]]),
+                "ys": jnp.asarray(features[idx_pairs["b"][sel]]),
+                "sim": jnp.asarray(idx_pairs["sim"][sel])}
 
 
 class IndexPairSource:
@@ -277,20 +294,14 @@ def pair_batches(pairs: dict, batch_size: int, seed: int = 0,
     """Infinite minibatch stream. ``balanced`` draws half S / half D per batch
     as in the paper's experimental setup (§5.2). Constraints within a batch
     are distinct (no duplicated pair rows)."""
-    rng = np.random.RandomState(seed)
-    sim_idx = np.nonzero(pairs["sim"] == 1)[0]
-    dis_idx = np.nonzero(pairs["sim"] == 0)[0]
-    n = pairs["sim"].shape[0]
-    while True:
-        if balanced and len(sim_idx) and len(dis_idx):
-            h = batch_size // 2
-            idx = np.concatenate([
-                sim_idx[distinct_draws(rng, len(sim_idx), h)],
-                dis_idx[distinct_draws(rng, len(dis_idx),
-                                        batch_size - h)]])
-        else:
-            idx = distinct_draws(rng, n, batch_size)
-        yield {k: jnp.asarray(v[idx]) for k, v in pairs.items()}
+    for idx in _batch_draws(pairs["sim"], batch_size, seed, balanced):
+        yield _gather_rows(pairs, idx)
+
+
+def _gather_rows(pairs: dict, idx: np.ndarray) -> dict:
+    """As ``_gather_indexed``, from a pair dict that holds the rows."""
+    with annotate("train.gather"):
+        return {k: jnp.asarray(v[idx]) for k, v in pairs.items()}
 
 
 def train_eval_split(cfg: PairDatasetConfig, n_train_sim: int, n_train_dis: int,

@@ -121,4 +121,5 @@ def dml_pair_fused(L, xs, ys, sim, *, lam: float = 1.0, margin: float = 1.0,
         ],
         scratch_shapes=[pltpu.VMEM((bB, bK), jnp.float32)],
         interpret=default_interpret(interpret),
+        name="dml_pair",
     )(sim, xs, ys, L)

@@ -123,4 +123,5 @@ def ivf_scan_topk_fused(probes, qp, g, gn, ids, *, cap: int, kk: int,
             jax.ShapeDtypeStruct((Nq, 1, kk), jnp.int32),
         ],
         interpret=default_interpret(interpret),
+        name="ivf_scan",
     )(probes, qp, g, gn, ids)

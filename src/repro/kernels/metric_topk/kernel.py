@@ -147,4 +147,5 @@ def metric_topk_fused(qp, gp, gn, *, k_top: int = 10,
             pltpu.VMEM((bQ, k_top), jnp.int32),     # running best indices
         ],
         interpret=default_interpret(interpret),
+        name="metric_topk",
     )(qp, gp, gn)

@@ -72,4 +72,5 @@ def pairwise_sqdist(xp, yp, xn, yn, *, block_n: int = 256,
         out_shape=jax.ShapeDtypeStruct((N, M), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bN, bM), jnp.float32)],
         interpret=default_interpret(interpret),
+        name="pairwise_dist",
     )(xp, yp, xn, yn)

@@ -163,4 +163,5 @@ def pq_adc_topk_fused(probes, tables, dc, codes, t, ids, *, n_codes: int,
             jax.ShapeDtypeStruct((Nq, 1, kk), jnp.int32),
         ],
         interpret=default_interpret(interpret),
+        name="pq_adc",
     )(probes, dc, tables, codes, t, ids)

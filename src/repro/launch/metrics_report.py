@@ -66,7 +66,11 @@ def render(snap: dict, n_events: int = 8) -> str:
     eng = {k: v.get("", 0.0) for k, v in
            ((n, _counter_values(snap, f"engine_{n}_total"))
             for n in ("requests", "queries", "device_queries",
-                      "busy_seconds", "cache_hits", "cache_misses"))}
+                      "cache_hits", "cache_misses"))}
+    # device-path wall time is the sum of the per-batch search histogram
+    search = snap.get("histograms", {}).get("engine_search_seconds", {})
+    eng["busy_seconds"] = search.get("values", {}).get("", {}).get("sum",
+                                                                    0.0)
     dev, busy = eng["device_queries"], eng["busy_seconds"]
     qps = dev / busy if busy > 0 else 0.0
     w(f"engine: {eng['requests']:.0f} requests / {eng['queries']:.0f} "

@@ -9,9 +9,11 @@ The measurement subsystem every other layer records into:
               implementation, and ``index_memory`` byte accounting;
   trace.py    ``Tracer`` / ``Trace`` / ``Span`` — request-scoped span
               trees on an injectable clock, deterministic sampling,
-              JSONL export.
+              JSONL export; ``annotate`` / ``annotate_step``, the
+              program's annotations in the JAX profiler's trace.
 
-Neither module imports jax or the serving stack (clocks are duck-typed),
+Neither module imports jax at import time (the first annotation does)
+nor the serving stack (clocks are duck-typed),
 so obs sits below everything: engine, scheduler, batcher, mutable index,
 snapshots, miner, and the closed loop all share one registry/tracer pair
 (see docs/observability.md for the metric catalog and span taxonomy).
@@ -22,4 +24,5 @@ from repro.obs.metrics import (DEFAULT_LATENCY_BUCKETS,  # noqa: F401
                                ScopedRegistry, index_memory, log_buckets,
                                merge_snapshots, parse_label_key, percentile)
 from repro.obs.trace import (NULL_SPAN, NullSpan, Span,  # noqa: F401
-                             Trace, Tracer, span_names)
+                             Trace, Tracer, annotate, annotate_step,
+                             span_names)

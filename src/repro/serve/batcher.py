@@ -158,12 +158,14 @@ class MicroBatcher:
             if q_span is not None:
                 q_span.end()
         # one batch serves many requests but the engine takes one span:
-        # the first *sampled* rider carries the coalesce + engine detail
+        # the first *sampled* rider carries the coalesce + engine detail,
+        # mirrored into the profiler's trace (they stay on this thread)
         carrier = next((tr for _, _, _, tr, _ in batch
                         if tr is not None and tr.sampled), None)
         c_span = e_span = None
         if carrier is not None:
-            c_span = carrier.span("coalesce").set_attrs(size=len(batch))
+            c_span = carrier.span("coalesce", mirror=True).set_attrs(
+                size=len(batch))
             e_span = carrier.span("engine", parent=c_span)
         # set_running_or_notify_cancel guards every resolution: a rider the
         # client cancelled while pending is skipped (resolving it would

@@ -112,8 +112,6 @@ class RetrievalEngine:
             "engine_device_queries_total",
             "query rows that reached the device (cache misses, incl. "
             "bucket pad overhead excluded)")
-        self._c_busy = r.counter(
-            "engine_busy_seconds_total", "device-path wall time")
         self._c_cache_hits = r.counter(
             "engine_cache_hits_total",
             "query rows served from the hot-query LRU")
@@ -177,7 +175,8 @@ class RetrievalEngine:
 
     @property
     def busy_s(self) -> float:
-        return self._c_busy.value()
+        """Device-path wall time: the sum of ``engine_search_seconds``."""
+        return self._h_search.sum()
 
     @property
     def cache_hits(self) -> int:
@@ -303,7 +302,6 @@ class RetrievalEngine:
         dists, idxs = jax.block_until_ready((dists, idxs))
         dt = self.clock.now() - t0
         d_sp.end()
-        self._c_busy.inc(dt)
         self._h_search.observe(dt)
 
         dists = np.asarray(dists[:n])

@@ -674,12 +674,14 @@ class RequestScheduler:
         # one batch serves many requests but the engine takes one span:
         # the first *sampled* rider carries the batch + engine detail
         # (other sampled riders in the same batch keep their queue span
-        # and outcome, without the shared-stage duplication)
+        # and outcome, without the shared-stage duplication). These spans
+        # open and end on this worker thread, so they mirror into the
+        # profiler's trace; the cross-thread queue and request spans do not
         carrier = next((r for r in live
                         if r.trace is not None and r.trace.sampled), None)
         b_span = e_span = None
         if carrier is not None:
-            b_span = carrier.trace.span("batch").set_attrs(
+            b_span = carrier.trace.span("batch", mirror=True).set_attrs(
                 size=len(live), level=(0 if controller is None
                                        else controller.level),
                 **{f"knob_{k}": v for k, v in knobs.items()})

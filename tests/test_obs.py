@@ -278,6 +278,44 @@ class TestTracer:
         tracer.finish(tr)                    # dropped, not buffered
         assert tracer.drain() == []
 
+    def test_mirror_holds_one_annotation_per_span_until_it_ends(
+            self, monkeypatch):
+        import repro.obs.trace as obs_trace
+        log = []
+
+        class Annotation:                    # stands in for the profiler's
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(obs_trace, "_annotation", Annotation)
+        tracer = Tracer(clock=FakeClock(), sample_rate=1.0)
+        tr = tracer.start_trace()
+        q = tr.span("queue")                 # cross-thread: not mirrored
+        b = tr.span("batch", mirror=True)
+        e = tr.span("engine", parent=b)      # a mirror's child mirrors
+        with e.child("pad"):
+            pass
+        e.end()
+        e.end()                              # one exit, at the first end
+        b.end()
+        q.end()
+        tracer.finish(tr)
+        assert log == [("enter", "batch"), ("enter", "engine"),
+                       ("enter", "pad"), ("exit", "pad"),
+                       ("exit", "engine"), ("exit", "batch")]
+        assert span_names(tracer.drain()[0]) == ["request", "queue",
+                                                 "batch", "engine", "pad"]
+        tracer.start_trace().span("x").child("y").end()
+        assert Tracer(clock=FakeClock()).start_trace().span(
+            "batch", mirror=True) is NULL_SPAN  # unsampled: nothing opens
+        assert len(log) == 6
+
     def test_trace_ids_unique_and_ring_bounded(self):
         tracer = Tracer(clock=FakeClock(), sample_rate=1.0, max_traces=4)
         ids = set()
@@ -408,8 +446,8 @@ class TestEngineObs:
         assert st["cache_hits"] == reg.counter(
             "engine_cache_hits_total").value() == 4
         assert st["cache_misses"] == 4
-        assert st["busy_s"] == reg.counter(
-            "engine_busy_seconds_total").value()
+        assert st["busy_s"] == reg.histogram(
+            "engine_search_seconds").sum() > 0
 
     def test_memory_gauges_follow_index_swap(self):
         rng = np.random.RandomState(0)
@@ -612,3 +650,17 @@ class TestMetricsReport:
         assert "hit rate" in text
         assert "== index memory ==" in text
         assert "index_compaction" in text
+
+    def test_render_reads_busy_time_from_the_search_histogram(self):
+        from repro.launch.metrics_report import render
+        clock = FakeClock()
+        eng = RetrievalEngine(_StubIndex(clock), k_top=5, cache_size=0,
+                              buckets=(8,), clock=clock)
+        for _ in range(4):
+            eng.search(np.zeros((2, 4), np.float32))
+        assert "engine_busy_seconds_total" not in eng.registry.snapshot()[
+            "counters"]
+        text = render(eng.registry.snapshot())
+        busy = eng.registry.histogram("engine_search_seconds").sum()
+        assert busy == eng.busy_s == 4 * _DT
+        assert f"(8 on device, {busy:.3f}s busy, {8 / busy:.0f} qps)" in text

@@ -15,6 +15,7 @@ imports every test file in every worker.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -49,11 +50,15 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernel):
+    """Compile ``fn`` and check that the kernel is in the program as a
+    ``tpu_custom_call`` under its stable ``name=``, which the trace's op
+    events carry (``%<kernel>.<n> = ... custom-call``)."""
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    assert re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(.*"
+                     rf"custom_call_target=\"tpu_custom_call\"", hlo), kernel
     return hlo
 
 
@@ -64,14 +69,15 @@ def test_metric_topk(one_chip):
     _compile(lambda L, q, gp, gn: metric_topk(L, q, gp, gn, k_top=10,
                                               interpret=False),
              one_chip, ((D_OUT, D_IN), F32), ((NQ, D_IN), F32),
-             ((GALLERY, D_OUT), F32), ((GALLERY,), F32))
+             ((GALLERY, D_OUT), F32), ((GALLERY,), F32), kernel="metric_topk")
 
 
 def test_ivf_scan(one_chip):
     _compile(lambda qp, pr, g, gn, ids: ivf_scan_topk(
                  qp, pr, g, gn, ids, kk=10, interpret=False),
              one_chip, ((NQ, D_OUT), F32), ((NQ, NPROBE), I32),
-             ((C, CAP, D_OUT), F32), ((C, CAP), F32), ((C, CAP), I32))
+             ((C, CAP, D_OUT), F32), ((C, CAP), F32), ((C, CAP), I32),
+             kernel="ivf_scan")
 
 
 def test_pq_adc(one_chip):
@@ -79,7 +85,7 @@ def test_pq_adc(one_chip):
                  tab, dc, pr, codes, t, ids, kk=RERANK, interpret=False),
              one_chip, ((NQ, S << BITS), F32), ((NQ, NPROBE), F32),
              ((NQ, NPROBE), I32), ((C, CAP, S), U8), ((C, CAP), F32),
-             ((C, CAP), I32))
+             ((C, CAP), I32), kernel="pq_adc")
 
 
 _PAIRS = (((D_OUT, D_IN), F32), ((PAIRS, D_IN), F32), ((PAIRS, D_IN), F32),
@@ -88,12 +94,14 @@ _PAIRS = (((D_OUT, D_IN), F32), ((PAIRS, D_IN), F32), ((PAIRS, D_IN), F32),
 
 def test_dml_pair_forward(one_chip):
     _compile(lambda L, xs, ys, sim: dml_pair_loss_fused(
-                 L, xs, ys, sim, 1.0, 1.0, False), one_chip, *_PAIRS)
+                 L, xs, ys, sim, 1.0, 1.0, False), one_chip, *_PAIRS,
+             kernel="dml_pair")
 
 
 def test_dml_pair_grad(one_chip):
     _compile(jax.grad(lambda L, xs, ys, sim: dml_pair_loss_fused(
-                 L, xs, ys, sim, 1.0, 1.0, False)), one_chip, *_PAIRS)
+                 L, xs, ys, sim, 1.0, 1.0, False)), one_chip, *_PAIRS,
+             kernel="dml_pair")
 
 
 def test_pairwise_dist(one_chip):
@@ -101,4 +109,4 @@ def test_pairwise_dist(one_chip):
     # off the lane width — ops.py pads all three
     _compile(lambda L, x, y: metric_sqdist_matrix(L, x, y, interpret=False),
              one_chip, ((D_OUT, D_IN), F32), ((500, D_IN), F32),
-             ((1_000, D_IN), F32))
+             ((1_000, D_IN), F32), kernel="pairwise_dist")
