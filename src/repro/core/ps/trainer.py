@@ -16,6 +16,7 @@ by construction at any rank (no projection step anywhere).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterator, Optional
 
 import jax
@@ -50,7 +51,41 @@ def _stacked(bs) -> dict:
     """The ``train.stack`` profiler span; its result goes straight to the
     stream's ``yield``, so no local holds it while the next is made."""
     with annotate("train.stack"):
-        return {k: jnp.stack([b[k] for b in bs]) for k in bs[0]}
+        return _stack_batches(bs)
+
+
+@jax.jit
+def _stack_batches(bs):
+    """Every key of the workers' batches stacked, in one program."""
+    return {k: jnp.stack([b[k] for b in bs]) for k in bs[0]}
+
+
+def _step_batches(streams) -> Iterator[dict]:
+    """The trainer's batches: several workers' stacked, one worker's as its
+    stream yields it, for a step made by ``_one_worker_step``."""
+    join = _stacked if len(streams) > 1 else _unstacked
+    while True:
+        yield join([next(s) for s in streams])
+
+
+def _unstacked(bs) -> dict:
+    """The ``train.stack`` span of one worker, which stacks nothing."""
+    with annotate("train.stack"):
+        return bs[0]
+
+
+def _one_worker_step(step):
+    """``step`` for one worker's batch without its worker axis. The size-1
+    axis is added inside the program, where XLA folds it into its
+    consumers; stacked outside, it is a copy of the whole batch. The
+    state is donated: the trainer holds no other reference to it, and a
+    step queued behind others then writes its ``L`` over its input's
+    instead of holding a new one. Profiles find the step program by
+    ``step_fn`` in its name."""
+    @functools.partial(jax.jit, donate_argnums=0)
+    def step_fn(state, batch):
+        return step(state, jax.tree.map(lambda x: x[None], batch))
+    return step_fn
 
 
 def make_worker_streams(pairs, n_workers: int, batch_size: int, seed: int):
@@ -104,14 +139,18 @@ def train_dml_distributed(cfg: DMLTrainConfig, pairs,
                                     compute_dtype=cfg.dml.compute_dtype)
 
     step_fn = sync.make_train_step(loss_fn, opt, cfg.ps, mesh)
-    batches = stack_worker_streams(make_worker_streams(
-        pairs, cfg.ps.n_workers, cfg.batch_size, cfg.ps.seed))
+    streams = make_worker_streams(pairs, cfg.ps.n_workers, cfg.batch_size,
+                                  cfg.ps.seed)
+    if len(streams) == 1:
+        step_fn = _one_worker_step(step_fn)
+    batches = _step_batches(streams)
 
     # each iteration's stages are profiler spans (``train`` over the step,
     # ``train.batch`` / ``train.step`` / ``train.log`` within it, and the
     # streams' ``train.draw`` / ``train.gather`` / ``train.stack``); they
     # reach a trace only while the profiler runs
     history = []
+    in_flight = None
     for t in range(cfg.steps):
         with annotate_step("train", t):
             with annotate("train.batch"):
@@ -119,6 +158,11 @@ def train_dml_distributed(cfg: DMLTrainConfig, pairs,
             with annotate("train.step"):
                 state, metrics = step_fn(state, batch)
             del batch           # not alive while the next one is made
+            # two steps queued at most: a host that runs ahead of the
+            # device would keep every queued step's batch and L on it
+            if in_flight is not None:
+                jax.block_until_ready(in_flight)
+            in_flight = metrics
             if t % cfg.log_every == 0 or t == cfg.steps - 1:
                 with annotate("train.log"):
                     rec = {"step": t, **jax.tree.map(float, metrics)}

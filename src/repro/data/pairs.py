@@ -248,19 +248,49 @@ def _batch_draws(sim: np.ndarray, batch_size: int, seed: int,
         yield sel
 
 
-def pair_batches_from_indices(features: np.ndarray, idx_pairs: dict,
-                              batch_size: int, seed: int = 0,
+def pair_batches_from_indices(features, idx_pairs: dict, batch_size: int,
+                              seed: int = 0,
                               balanced: bool = True) -> Iterator[dict]:
     """Minibatch stream gathering features on the fly (memory-bounded).
-    Constraints within a batch are distinct (no duplicated pair rows)."""
-    for sel in _batch_draws(idx_pairs["sim"], batch_size, seed, balanced):
-        yield _gather_indexed(features, idx_pairs, sel)
+    Constraints within a batch are distinct (no duplicated pair rows).
+
+    On a device store (a ``jax.Array``) each batch is one call of the
+    compiled ``pair_rows``, which indexes in int32; a host store is
+    gathered in numpy and the batch's rows are sent to the device."""
+    if isinstance(features, jax.Array):
+        if features.shape[0] > np.iinfo(np.int32).max:
+            raise ValueError(
+                f"a device store of {features.shape[0]} rows cannot be "
+                f"indexed in int32; keep it under 2**31 rows")
+        gather = _gather_on_device
+    else:
+        gather = _gather_indexed
+    return (gather(features, idx_pairs, sel)
+            for sel in _batch_draws(idx_pairs["sim"], batch_size, seed,
+                                    balanced))
+
+
+@jax.jit
+def pair_rows(features, idx):
+    """One batch from a device store: ``idx`` is int32 (3, B), the pairs'
+    ``a`` and ``b`` rows and their ``sim`` labels. Returns ``xs``, ``ys``
+    (B, d) and ``sim`` (B,) int32."""
+    return {"xs": features[idx[0]], "ys": features[idx[1]], "sim": idx[2]}
+
+
+def _gather_on_device(features, idx_pairs: dict, sel: np.ndarray) -> dict:
+    """As ``_gather_indexed``, on a device store: the drawn pairs go to the
+    device as one small int32 array and ``pair_rows`` gathers the batch."""
+    with annotate("train.gather"):
+        return pair_rows(features, np.stack(
+            [idx_pairs[k][sel] for k in ("a", "b", "sim")]).astype(np.int32))
 
 
 def _gather_indexed(features, idx_pairs: dict, sel: np.ndarray) -> dict:
-    """One batch's feature gathers, the ``train.gather`` profiler span.
-    The batch goes straight to the stream's ``yield``: a generator local
-    would keep it alive while the next batch is gathered."""
+    """One batch's feature gathers from a host store, the ``train.gather``
+    profiler span. The batch goes straight to the stream's ``yield``: a
+    generator local would keep it alive while the next batch is
+    gathered."""
     with annotate("train.gather"):
         return {"xs": jnp.asarray(features[idx_pairs["a"][sel]]),
                 "ys": jnp.asarray(features[idx_pairs["b"][sel]]),
@@ -275,7 +305,7 @@ class IndexPairSource:
     is the pluggable-source contract of ``core/ps/trainer``: it
     partitions the index pairs over workers (paper §4.1) and streams each
     shard through ``pair_batches_from_indices``. ``features`` may be a
-    device array; the per-batch gathers then run on the device.
+    device array; each batch is then one gather program on the device.
     """
 
     def __init__(self, features, idx_pairs: dict):

@@ -7,6 +7,8 @@ import pytest
 
 from repro.core import dml
 from repro.data import pairs as pairdata
+from repro.data.loader import partition_pairs
+from repro.optim import sgd
 
 jax.config.update("jax_enable_x64", False)
 
@@ -247,3 +249,103 @@ class TestIndexPairSource:
         L, hist = train_dml_distributed(tcfg, src)
         assert L.shape == (8, 16) and len(hist) == 5
         assert np.isfinite([h["loss"] for h in hist]).all()
+
+    @pytest.mark.parametrize("n_workers", [1, 3])
+    @pytest.mark.parametrize("store", ["device", "host"])
+    def test_batches_are_the_stores_rows(self, store, n_workers,
+                                         monkeypatch):
+        """Each worker's batch holds, bit for bit, the rows numpy indexing
+        gives for its seed's draw; a device store gathers each batch in one
+        ``pair_rows`` call, a host store in none."""
+        cfg = pairdata.PairDatasetConfig(n_samples=500, feat_dim=24,
+                                         n_classes=5, seed=2)
+        x, y = pairdata.make_features(cfg)
+        idx = pairdata.sample_pair_indices(y, 600, 600, seed=3)
+        calls = []
+        pair_rows = pairdata.pair_rows
+        monkeypatch.setattr(pairdata, "pair_rows", lambda *a, **k: (
+            calls.append(1), pair_rows(*a, **k))[1])
+        feats = jnp.asarray(x) if store == "device" else x
+        streams = pairdata.IndexPairSource(feats, idx).worker_streams(
+            n_workers, 40, seed=7)
+        shards = partition_pairs(idx, n_workers)
+        for w, (stream, shard) in enumerate(zip(streams, shards)):
+            draws = pairdata._batch_draws(shard["sim"], 40, 7 + w, True)
+            for _ in range(3):
+                b, sel = next(stream), next(draws)
+                assert b["xs"].shape == b["ys"].shape == (40, 24)
+                assert b["sim"].dtype == jnp.int32
+                np.testing.assert_array_equal(np.asarray(b["xs"]),
+                                              x[shard["a"][sel]])
+                np.testing.assert_array_equal(np.asarray(b["ys"]),
+                                              x[shard["b"][sel]])
+                np.testing.assert_array_equal(np.asarray(b["sim"]),
+                                              shard["sim"][sel])
+        assert len(calls) == (3 * n_workers if store == "device" else 0)
+
+    def test_device_store_past_int32_is_refused(self):
+        from unittest import mock
+        store = mock.Mock(spec=jax.Array)
+        store.shape = (2 ** 31, 24)
+        idx = pairdata.sample_pair_indices(np.arange(40) % 4, 20, 20)
+        with pytest.raises(ValueError, match="int32"):
+            pairdata.pair_batches_from_indices(store, idx, 8)
+        store.shape = (2 ** 31 - 1, 24)
+        pairdata.pair_batches_from_indices(store, idx, 8)   # made, not run
+
+    def test_one_worker_trainer_matches_the_stacked_step(self):
+        """One worker's batches reach the step unstacked; ``L`` and the
+        logged losses match stacked batches through the step as it is
+        built, and the step program keeps the name the benchmark's trace
+        reduction looks for."""
+        import importlib.util
+        import os
+        from repro.core.ps import sync, trainer
+        from repro.core import losses
+        cfg = pairdata.PairDatasetConfig(n_samples=300, feat_dim=16,
+                                         n_classes=4, seed=0)
+        x, y = pairdata.make_features(cfg)
+        src = pairdata.IndexPairSource(
+            jnp.asarray(x), pairdata.sample_pair_indices(y, 400, 400))
+        tcfg = trainer.DMLTrainConfig(
+            dml=dml.DMLConfig(feat_dim=16, proj_dim=8),
+            ps=sync.PSConfig(n_workers=1, seed=3), batch_size=64, steps=12,
+            lr=1e-2, log_every=5)
+        L, hist = trainer.train_dml_distributed(tcfg, src)
+
+        def loss_fn(p, b):
+            return losses.dml_pair_loss(p, b, lam=tcfg.dml.lam,
+                                        margin=tcfg.dml.margin)
+
+        opt = sgd(tcfg.lr)
+        mesh = sync.make_worker_mesh(1, tcfg.ps.axis)
+        state = sync.init_state(
+            opt, dml.init_params(tcfg.dml, jax.random.PRNGKey(3)), tcfg.ps)
+        step = sync.make_train_step(loss_fn, opt, tcfg.ps, mesh)
+        batches = trainer.stack_worker_streams(
+            src.worker_streams(1, 64, seed=3))
+        want = []
+        for t in range(12):
+            state, m = step(state, next(batches))
+            if t % 5 == 0 or t == 11:
+                want.append(float(m["loss"]))
+        np.testing.assert_allclose([h["loss"] for h in hist], want,
+                                   rtol=1e-6)
+        np.testing.assert_allclose(L, sync.worker_mean(state.params),
+                                   rtol=1e-6)
+
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "chip", "harness",
+            "trace_metrics.py")
+        spec = importlib.util.spec_from_file_location("_trace_metrics", path)
+        tm = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tm)
+        def program(lowered):
+            return lowered.as_text().split("module @", 1)[1].split()[0]
+
+        one = trainer._one_worker_step(step)
+        name = program(one.lower(state, next(src.worker_streams(1, 64, 3)[0])))
+        assert tm.STEP_PROGRAM.search(name), name
+        gather = program(pairdata.pair_rows.lower(
+            src.features, np.zeros((3, 64), np.int32)))
+        assert not tm.STEP_PROGRAM.search(gather), gather
