@@ -5,19 +5,21 @@
         [--faults half,noexchange,altered --fault-seeds 301-303] \
         [--seconds 3] [--out <file.json>]
 
-For each seed it builds the cell as a run does, drives the timed path
-through its set-up steps (training) or a window of ``--seconds`` at the
-cell's own rate (serving), and prints the compared numbers of the program
-(``program``); for the seeds of ``--control-seeds`` also those of the
-control (``control``: the reference computed in bfloat16, put in the
-program's place, on the same batches or requests); and for each fault and
+For each seed it runs the cell's driver as a run does, untraced, with a
+window of ``--seconds``, and prints the compared numbers of the program
+(``program``) with the window's failed requests or steps; for the seeds
+of ``--control-seeds`` also those of the control (``control``: the
+reference computed in bfloat16, put in the program's place, on the same
+batches or requests); and for each fault and
 fault seed those of the program with the fault planted (``fault:<name>``):
 
 * ``unchanged``: the train step returns its state unchanged;
 * ``half``: the loss sees the first half of each batch only (training), or
   the second half of each served batch gets the first half's answers;
 * ``noexchange``: the workers' ``pmean`` is left out (several workers);
-* ``altered``: one served id of each batch is changed where it is made.
+* ``altered``: one served id of each batch is changed where it is made;
+
+and any fault of the cell's driver's own ``FAULTS``.
 
 The lower reading of a number is the largest the program gives over the
 seeds; its upper reading the smallest the control or a fault gives.
@@ -50,8 +52,14 @@ def seeds(text: str):
 
 
 @contextlib.contextmanager
-def fault(name: str):
-    """Plant one fault in the program for the duration of the block."""
+def fault(name: str, driver=None):
+    """Plant one fault in the program for the duration of the block: one
+    of the shared faults above, or one of ``driver``'s ``FAULTS``."""
+    own = getattr(driver, "FAULTS", {})
+    if name in own:
+        with own[name]():
+            yield
+        return
     import jax
     import numpy as np
     from repro.core import losses
@@ -101,7 +109,8 @@ def fault(name: str):
             return d, i
         patch(engine_mod.RetrievalEngine, "search", altered)
     elif name:
-        raise ValueError(f"unknown fault {name!r}")
+        raise ValueError(f"unknown fault {name!r}; the driver's own are "
+                         f"{sorted(own)}")
     try:
         yield
     finally:
@@ -109,51 +118,15 @@ def fault(name: str):
             setattr(obj, attr, old)
 
 
-def train_numbers(cfg, traffic, seed, *, control=False):
-    """{"program": numbers[, "control": numbers]} of one seed's set-up
-    steps, run as a cell's run makes them."""
-    from harness import train
-    start = traffic["window_start"]
-    env = train.build(cfg, seed)
-    hook = train.Hook(float("inf"), start)
-    history, src = train.drive(env, hook, start + 1, steps=start + 1)
-    steps, nums = train.identify(env, src, start + 1)
-    out = {"program": nums}
-    if not any(nums.values()):
-        seen = train.program_seen(cfg, history, hook, start)
-        prog, low = train.check(env, cfg, seen, steps, start,
-                                control=control)
-        nums.update(prog)
-        if low is not None:
-            out["control"] = low
-    return out
-
-
-def serve_numbers(cfg, traffic, seed, seconds, *, control=False):
-    """As ``train_numbers``, for a serving cell: one set-up and a window of
-    ``seconds`` at the traffic's own rate."""
-    import time as _time
-
-    from harness import cells, data, serve
-    key = data.base_key(seed)
-    L, pool, stack = serve.setup(key, cfg, traffic)
-    due, qid = data.arrivals(traffic["rate_qps"], seconds, traffic["lead_s"],
-                             seed, traffic["pool"])
-    rp = serve.Replay(stack.scheduler, pool, due, qid, traffic)
-    cells.settle_heap()
-    t0 = _time.perf_counter() + 0.05 + traffic["lead_s"]
-    rp.run(t0)
-    rp.wait(t0 + seconds + 60)
-    ws = serve.window_stats(rp, t0, seconds, _time.perf_counter())
-    stack.close()
-    rp.scheduler = None
-    del stack
-    cells.release_heap()
-    nums, low = serve.check(key, L, cfg, pool, rp, ws["in_window"], seed,
-                            control=control)
-    nums["failed"] = float(ws["n_failed"])
-    nums["requests_unanswered"] = float(ws["n_unanswered"])
-    out = {"program": nums}
+def readings(work, cfg, traffic, seed, seconds, *, control=False):
+    """{"program": numbers[, "control": numbers]} of one untraced run of a
+    cell's driver, the program's with the window's ``failed``."""
+    import jax
+    ctx, nums, low = spec.driver(traffic["kind"]).drive(
+        cfg, traffic, seed=seed, seconds=seconds, prof=None,
+        t_start=time.perf_counter(), devices=jax.devices()[:work["chips"]],
+        control=control)
+    out = {"program": dict(nums, failed=float(ctx["failed"]))}
     if low is not None:
         out["control"] = low
     return out
@@ -176,12 +149,7 @@ def main() -> None:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     work, cfg, traffic, _ = spec.cell(spec.benchmark(), args.workload)
-
-    def numbers(seed, control=False):
-        if traffic["kind"] == "train":
-            return train_numbers(cfg, traffic, seed, control=control)
-        return serve_numbers(cfg, traffic, seed, args.seconds,
-                             control=control)
+    driver = spec.driver(traffic["kind"])
 
     rows = []
     ctl = set(seeds(args.control_seeds))
@@ -190,8 +158,9 @@ def main() -> None:
                for s in seeds(args.fault_seeds)])
     for name, seed in plan:
         t = time.perf_counter()
-        with fault(name):
-            out = numbers(seed, control=not name and seed in ctl)
+        with fault(name, driver):
+            out = readings(work, cfg, traffic, seed, args.seconds,
+                           control=not name and seed in ctl)
         for what, nums in out.items():
             if name:
                 what = f"fault:{name}"

@@ -3,16 +3,13 @@
 from __future__ import annotations
 
 import gc
-import math
 import os
 import shutil
 import sys
-import time
 
 import jax
-import numpy as np
 
-from harness import data, serve, spec, train, trace as tracing
+from harness import spec, trace as tracing
 
 
 class Profile:
@@ -62,7 +59,7 @@ def release_heap() -> None:
     gc.collect()
 
 
-def _peak_bytes(devices) -> int:
+def peak_bytes(devices) -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in devices]
     return int(max(peaks))
@@ -73,9 +70,9 @@ def run(work, cfg, traffic, limits, metric_entries, *, seed, seconds, trace,
     """Run the cell once; returns (result dict, checks dict)."""
     devices = jax.devices()[:work["chips"]]
     prof = Profile(out_dir) if trace else None
-    drive = {"train": _train, "serve": _serve}[traffic["kind"]]
-    ctx, nums = drive(cfg, traffic, seed=seed, seconds=seconds, prof=prof,
-                      t_start=t_start, devices=devices)
+    drive = spec.driver(traffic["kind"]).drive
+    ctx, nums, _ = drive(cfg, traffic, seed=seed, seconds=seconds,
+                         prof=prof, t_start=t_start, devices=devices)
     ctx.update(cfg=cfg, traffic=traffic, peaks=peaks, chips=len(devices))
     correct, checks = spec.judge(nums, limits)
     if prof is not None:
@@ -100,92 +97,3 @@ def run(work, cfg, traffic, limits, metric_entries, *, seed, seconds, trace,
         result["device"]["window_s"] = tr["window_s"]
         result["breakdown"] = tr["breakdown"]
     return result, checks
-
-
-def _train(cfg, traffic, *, seed, seconds, prof, t_start, devices):
-    start = traffic["window_start"]
-    env = train.build(cfg, seed)
-
-    def on_start():
-        settle_heap()
-        if prof is not None:
-            prof.start()
-            prof.mark()
-
-    hook = train.Hook(seconds, start, on_start=on_start,
-                      on_stop=prof.stop if prof is not None else None)
-    history, src = train.drive(env, hook, n_record=start + 1)
-    peak = _peak_bytes(devices)
-    release_heap()
-    steps = hook.step1 - hook.step0
-    in_window = [h for h in history if h["step"] > hook.step0]
-    ctx = {
-        "kind": "train",
-        "setup_s": hook.t0 - t_start,
-        "window_s": hook.t1 - hook.t0,
-        "steps": steps,
-        "pairs": steps * cfg["batch_size"] * cfg["n_workers"],
-        "attempted": steps,
-        "failed": sum(not math.isfinite(h["loss"]) for h in in_window),
-        "memory_peak_bytes": peak,
-    }
-    steps, nums = train.identify(env, src, start + 1)
-    if not any(nums.values()):
-        seen = train.program_seen(cfg, history, hook, start)
-        nums.update(train.check(env, cfg, seen, steps, start)[0])
-    return ctx, nums
-
-
-def _serve(cfg, traffic, *, seed, seconds, prof, t_start, devices):
-    key = data.base_key(seed)
-    due, qid = data.arrivals(traffic["rate_qps"], seconds,
-                             traffic["lead_s"], seed, traffic["pool"])
-    L, pool, stack = serve.setup(key, cfg, traffic, traced=prof is not None,
-                                 max_traces=len(due) + 1024)
-    rp = serve.Replay(stack.scheduler, pool, due, qid, traffic)
-    marks = {}
-
-    def on_window():
-        if prof is not None:
-            prof.mark()
-        marks["hist0"] = stack.batch_hist()
-
-    settle_heap()
-    if prof is not None:
-        prof.start()
-    t_first = time.perf_counter() + 0.05
-    t0 = t_first + traffic["lead_s"]
-    rp.run(t0, on_window=on_window)
-    serve.sleep_until(t0 + seconds)
-    hist1 = stack.batch_hist()
-    if prof is not None:
-        prof.stop()
-    rp.wait(t0 + seconds + 60.0)
-    t_end = time.perf_counter()
-    ws = serve.window_stats(rp, t0, seconds, t_end)
-    closed = stack.close()
-    peak = _peak_bytes(devices)
-    spans = stack.tracer.drain()
-    rp.scheduler = None
-    del stack
-    release_heap()
-    mono0 = t0 + time.monotonic() - time.perf_counter()   # spans' clock
-    ctx = {
-        "kind": "serve",
-        "setup_s": t0 - t_start,
-        "window_s": seconds,
-        "t0": t0,
-        "latency_s": ws["latency_s"],
-        "lag_s": ws["lag_s"],
-        "completed_in_window": ws["completed_in_window"],
-        "attempted": ws["n_due"],
-        "failed": ws["n_failed"] + (0 if closed else 1),
-        "memory_peak_bytes": peak,
-        "spans": [s for s in spans
-                  if mono0 <= s["root"]["t_start"] < mono0 + seconds],
-        "batches": (hist1[0] - marks["hist0"][0],
-                    hist1[1] - marks["hist0"][1]),
-    }
-    nums = serve.check(key, L, cfg, pool, rp, ws["in_window"], seed)[0]
-    nums["requests_unanswered"] = float(ws["n_unanswered"])
-    return ctx, nums

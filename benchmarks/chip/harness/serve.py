@@ -1,5 +1,8 @@
 """Serving cells: open-loop k-NN requests through the scheduler.
 
+The driver of the traffic kind ``serve`` (``drive``); its open-loop pieces (``setup``, ``Replay``, ``window_stats``) also serve
+``sweep.py``.
+
 Set-up makes the serving factor ``L`` and the gallery from the seed on the
 device, projects the gallery through the program's own index-build
 projection, builds ``ExactIndex -> RetrievalEngine -> RequestScheduler``
@@ -25,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import data, reference
+from harness import cells, data, reference
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -254,3 +257,64 @@ def _gaps(served, truth, qp_rows, gp, M: int) -> dict:
         "dist_err": float(dist_err.max()),
         "rank_excess": float(max(excess.max(), 0.0)),
     }
+
+
+def drive(cfg, traffic, *, seed, seconds, prof, t_start, devices,
+          control=False):
+    """One run of a serving cell: set-up, the open-loop window of
+    ``seconds`` at the traffic's rate, a wait for every request due in it,
+    then the check. Returns (ctx, numbers, control numbers or None)."""
+    key = data.base_key(seed)
+    due, qid = data.arrivals(traffic["rate_qps"], seconds,
+                             traffic["lead_s"], seed, traffic["pool"])
+    L, pool, stack = setup(key, cfg, traffic, traced=prof is not None,
+                           max_traces=len(due) + 1024)
+    rp = Replay(stack.scheduler, pool, due, qid, traffic)
+    marks = {}
+
+    def on_window():
+        if prof is not None:
+            prof.mark()
+        marks["hist0"] = stack.batch_hist()
+
+    cells.settle_heap()
+    if prof is not None:
+        prof.start()
+    t_first = time.perf_counter() + 0.05
+    t0 = t_first + traffic["lead_s"]
+    rp.run(t0, on_window=on_window)
+    sleep_until(t0 + seconds)
+    hist1 = stack.batch_hist()
+    if prof is not None:
+        prof.stop()
+    rp.wait(t0 + seconds + 60.0)
+    t_end = time.perf_counter()
+    ws = window_stats(rp, t0, seconds, t_end)
+    closed = stack.close()
+    peak = cells.peak_bytes(devices)
+    spans = stack.tracer.drain()
+    rp.scheduler = None
+    del stack
+    cells.release_heap()
+    mono0 = t0 + time.monotonic() - time.perf_counter()   # spans' clock
+    ctx = {
+        "kind": "serve",
+        "setup_s": t0 - t_start,
+        "window_s": seconds,
+        "t0": t0,
+        "latency_s": ws["latency_s"],
+        "lag_s": ws["lag_s"],
+        "completed_in_window": ws["completed_in_window"],
+        "attempted": ws["n_due"],
+        "failed": ws["n_failed"] + (0 if closed else 1),
+        "memory_peak_bytes": peak,
+        "spans": [s for s in spans
+                  if mono0 <= s["root"]["t_start"] < mono0 + seconds],
+        "batches": (hist1[0] - marks["hist0"][0],
+                    hist1[1] - marks["hist0"][1]),
+    }
+    nums, low = check(key, L, cfg, pool, rp, ws["in_window"], seed,
+                      control=control)
+    nums["requests_unanswered"] = float(ws["n_unanswered"])
+    return ctx, nums, low
+

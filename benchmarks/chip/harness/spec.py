@@ -2,13 +2,52 @@
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
 configuration's entry names its file; the mix is
-``traffic/<traffic>.json``; the limits of the cell's correctness check are
-``limits/<cell>.json``; each metric is read by ``metrics/<metric>.py``.
-Adding a cell, a mix or a metric is adding files and entries.
+``traffic/<traffic>.json``, and its ``kind`` names the module
+``harness/<kind>.py`` that drives it (``driver``); the limits of the
+cell's correctness check are ``limits/<cell>.json``; each metric is read
+by ``metrics/<metric>.py``. Adding a cell, a mix, a kind or a metric is
+adding files and entries.
+
+A driver module exposes
+
+* ``drive(cfg, traffic, *, seed, seconds, prof, t_start, devices,
+  control=False) -> (ctx, numbers, control numbers or None)``: one run of
+  a cell: set-up, the measured window (under ``prof``, a
+  ``cells.Profile``, when the run is traced), and the correctness check;
+  ``numbers`` are the compared numbers by name, and with ``control`` the
+  control's too. ``cells.run`` and ``calibrate.py`` both call it, so the
+  limits are read on the timed path;
+* optionally ``FAULTS``: ``{name: () -> context manager}``, faults of its
+  own that ``calibrate.fault`` can plant besides the shared ones.
+
+``ctx["kind"]`` is the class of result, which says which metric readers
+apply; it is not the traffic kind. Every class gives ``kind``, ``setup_s``
+(process start to the window's start), ``window_s``, ``attempted``,
+``failed`` and ``memory_peak_bytes``; ``cells.run`` adds ``cfg``,
+``traffic``, ``peaks``, ``chips`` and, in a traced run, ``trace``.
+
+* ``"train"``: ``steps`` and ``pairs`` trained in the window. Read by
+  ``pairs_per_s`` and the ``.train`` readers; the host-loop readers take
+  the trainer's ``train.*`` stages from the trace.
+* ``"serve"``: ``t0`` (the window's start, host clock),
+  ``completed_in_window`` (requests due in the window and completed in
+  it; a closed loop counts every request completed in it),
+  ``latency_s`` and ``lag_s`` (per request of the window: due time to
+  result, and how late its submit ran; a closed loop has no due times,
+  so its latency runs from the submit and ``lag_s`` is empty),
+  ``spans`` (the program's ``obs`` traces whose root starts in the
+  window) and ``batches``
+  ((requests, batches) the scheduler dispatched in the window). Read by
+  ``qps`` and the ``.serve`` readers; ``mfu.serve`` and
+  ``metric_topk_roofline`` also read ``cfg``'s ``feat_dim``,
+  ``proj_dim``, ``gallery_rows`` and ``max_batch``. A new serving driver
+  that gives these keys gets those metrics unchanged.
 """
 
 from __future__ import annotations
 
+import glob
+import importlib
 import importlib.util
 import json
 import math
@@ -40,6 +79,29 @@ def cell(bench: dict, name: str):
     lim_path = os.path.join(HERE, "limits", name + ".json")
     limits = load_json(lim_path) if os.path.exists(lim_path) else {}
     return work, cfg, traffic, limits
+
+
+def driver(kind: str):
+    """The module ``harness/<kind>.py`` that drives cells of a traffic
+    kind. An unknown kind, or a module with no ``drive``, fails with the
+    kinds found."""
+    path = os.path.join(HERE, "harness", kind + ".py")
+    if kind.isidentifier() and os.path.exists(path):
+        mod = importlib.import_module("harness." + kind)
+        if hasattr(mod, "drive"):
+            return mod
+    raise KeyError(f"no driver for traffic kind {kind!r} "
+                   f"(harness/{kind}.py with a drive function); the kinds "
+                   f"found are {kinds()}")
+
+
+def kinds() -> list:
+    """The traffic kinds of this checkout: the modules of ``harness/``
+    that have a ``drive`` function."""
+    names = sorted(os.path.basename(p)[:-3] for p in
+                   glob.glob(os.path.join(HERE, "harness", "*.py")))
+    return [n for n in names if not n.startswith("_")
+            and hasattr(importlib.import_module("harness." + n), "drive")]
 
 
 def metrics_for(bench: dict, name: str, trace: bool):

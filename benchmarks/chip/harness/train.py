@@ -1,5 +1,7 @@
 """Training cells: Eq. 4 through ``train_dml_distributed``, timed by its hook.
 
+The driver of the traffic kind ``train`` (``drive``).
+
 One call of the trainer is the whole run: its first steps are set-up (the
 step compiles at step 0) and feed the correctness check, and the steps
 after ``window_start`` are the measured window. ``step_hook`` is the seam:
@@ -22,7 +24,7 @@ is checked against the store's labels.
 
 from __future__ import annotations
 
-import dataclasses
+import math
 import time
 import traceback
 
@@ -30,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import data, reference
+from harness import cells, data, reference
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -168,17 +170,14 @@ def build(cfg: dict, seed: int):
                 ps_seed=ps_seed, source=IndexPairSource(feats, pool))
 
 
-def drive(env: dict, hook: Hook, n_record: int, *, steps=None):
-    """Run the trainer until the hook closes the window, or for ``steps``.
-    Returns (history, recorded source)."""
+def run_trainer(env: dict, hook: Hook, n_record: int):
+    """Run the trainer until the hook closes the window. Returns (history,
+    recorded source)."""
     from repro.core.ps.trainer import train_dml_distributed
 
     src = RecordedSource(env["source"], n_record, env["probe"])
-    tcfg = env["tcfg"]
-    if steps is not None:
-        tcfg = dataclasses.replace(tcfg, steps=steps)
     try:
-        _, history = train_dml_distributed(tcfg, src, step_hook=hook)
+        _, history = train_dml_distributed(env["tcfg"], src, step_hook=hook)
     except WindowClosed as e:
         history = trainer_history(e.__traceback__)
         traceback.clear_frames(e.__traceback__)
@@ -280,3 +279,45 @@ def compare(ref, seen, L0, lr: float) -> dict:
         "change_norm_gap": gap(last.astype(f32) - L0,
                                r_last.astype(f32) - L0),
     }
+
+
+def drive(cfg, traffic, *, seed, seconds, prof, t_start, devices,
+          control=False):
+    """One run of a training cell: the trainer from the seed, its first
+    ``window_start`` steps as set-up, ``seconds`` of steps as the window,
+    then the check of the set-up steps. Returns (ctx, numbers, control
+    numbers or None)."""
+    start = traffic["window_start"]
+    env = build(cfg, seed)
+
+    def on_start():
+        cells.settle_heap()
+        if prof is not None:
+            prof.start()
+            prof.mark()
+
+    hook = Hook(seconds, start, on_start=on_start,
+                on_stop=prof.stop if prof is not None else None)
+    history, src = run_trainer(env, hook, n_record=start + 1)
+    peak = cells.peak_bytes(devices)
+    cells.release_heap()
+    steps = hook.step1 - hook.step0
+    in_window = [h for h in history if h["step"] > hook.step0]
+    ctx = {
+        "kind": "train",
+        "setup_s": hook.t0 - t_start,
+        "window_s": hook.t1 - hook.t0,
+        "steps": steps,
+        "pairs": steps * cfg["batch_size"] * cfg["n_workers"],
+        "attempted": steps,
+        "failed": sum(not math.isfinite(h["loss"]) for h in in_window),
+        "memory_peak_bytes": peak,
+    }
+    steps, nums = identify(env, src, start + 1)
+    low = None
+    if not any(nums.values()):
+        seen = program_seen(cfg, history, hook, start)
+        prog, low = check(env, cfg, seen, steps, start, control=control)
+        nums.update(prog)
+    return ctx, nums, low
+

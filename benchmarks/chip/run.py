@@ -4,16 +4,16 @@
         --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
-traffic mix; its traffic's ``kind`` picks the code that drives it
-(``harness/train.py`` or ``harness/serve.py``). The run makes its data on
-the device from ``--seed``, warms every shape it will use (set-up),
-measures for ``--seconds``, checks what the timed path produced against a
-plain
-reference, and prints one JSON object as its last line of stdout. With
-``--trace 1`` the window runs under the JAX profiler and the line carries
-the per-layer metrics, the device's busy time and a breakdown. There is
-no CPU fallback: without a TPU, or with fewer chips than the cell asks
-for, it exits non-zero and prints no result.
+traffic mix; its traffic's ``kind`` names the module that drives it,
+``harness/<kind>.py`` (``harness/spec.py`` says what such a driver
+exposes). The run makes its data on the device from ``--seed``, warms
+every shape it will use (set-up), measures for ``--seconds``, checks what
+the timed path produced against a plain reference, and prints one JSON
+object as its last line of stdout. With ``--trace 1`` the window runs
+under the JAX profiler and the line carries the per-layer metrics, the
+device's busy time and a breakdown. There is no CPU fallback: without a
+TPU, or with fewer chips than the cell asks for, it exits non-zero and
+prints no result.
 """
 
 from __future__ import annotations

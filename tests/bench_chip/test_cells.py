@@ -1,5 +1,7 @@
-"""Each cell's set-up, window and check, end to end at a tiny size on the
-CPU: the run's result line is whole and its check passes."""
+"""Each one-chip cell of BENCHMARK.json, its set-up, window and check end
+to end at a tiny size on the CPU: the run's result line is whole, with
+the cell's end-to-end metrics, and its check passes. The four-worker
+cell runs on four virtual CPU devices in a process of its own."""
 
 import json
 import os
@@ -8,18 +10,16 @@ import sys
 
 import pytest
 
-from tiny import ROOT, run_tiny
-
-E2E = {"imnet1m.train": {"pairs_per_s", "setup_s"},
-       "imnet63k.train": {"pairs_per_s", "setup_s"},
-       "imnet1m.serve": {"qps", "setup_s"}}
+from harness import spec
+from tiny import ROOT, benchmark_cells, run_tiny
 
 
-@pytest.mark.parametrize("cell", sorted(E2E))
+@pytest.mark.parametrize("cell", benchmark_cells(chips=1))
 def test_cell_runs_and_checks_out(cell):
     result, checks = run_tiny(cell)
     assert result["correct"], checks
-    assert set(result["metrics"]) == E2E[cell]
+    e2e = spec.metrics_for(spec.benchmark(), cell, False)
+    assert set(result["metrics"]) == {m["name"] for m in e2e}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["attempted"] > 0
     assert result["device"]["platform"] == "cpu"

@@ -87,3 +87,14 @@ def test_the_peaks_table_holds_the_v5e():
     assert v5e["bf16_flops"] == 197e12 and v5e["int8_ops"] == 393e12
     assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["hbm_bytes"] == 16e9
     assert "TPU v5e" in peaks["source"]
+
+
+def test_sweep_refuses_a_kind_that_is_not_open_loop():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/chip/sweep.py", "--workload",
+         "imnet1m.train", "--rates", "100"], cwd=ROOT, env=env,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "not an open-loop serving kind" in proc.stderr
+    assert "setup, Replay, window_stats" in proc.stderr

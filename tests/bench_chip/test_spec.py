@@ -7,6 +7,7 @@ import re
 import pytest
 
 from harness import spec
+from tiny import benchmark_cells, stand_in
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -52,12 +53,32 @@ def test_every_cell_finds_its_pieces(benchmark_json):
         pairs.add((w["config"], w["traffic"]))
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         work, cfg, traffic, limits = spec.cell(benchmark_json, w["name"])
-        assert traffic["kind"] in ("train", "serve")
+        assert hasattr(spec.driver(traffic["kind"]), "drive")
         assert limits.get("numbers"), f"no limits for {w['name']}"
         e2e = spec.metrics_for(benchmark_json, w["name"], False)
         names = {m["name"] for m in e2e}
         assert "setup_s" in names and len(names) >= 2
         assert spec.metrics_for(benchmark_json, w["name"], True)
+
+
+def test_an_unknown_kind_fails_with_the_kinds_found(benchmark_json):
+    with pytest.raises(KeyError) as err:
+        spec.driver("no_such_kind")
+    for w in benchmark_json["workloads"]:
+        kind = spec.cell(benchmark_json, w["name"])[2]["kind"]
+        assert repr(kind) in str(err.value) and kind in spec.kinds()
+    for bad in ("spec", "../run", "cells"):      # modules, but no drivers
+        with pytest.raises(KeyError):
+            spec.driver(bad)
+
+
+@pytest.mark.parametrize("cell", benchmark_cells())
+def test_every_cell_has_a_cpu_stand_in(cell, benchmark_json):
+    work = next(w for w in benchmark_json["workloads"] if w["name"] == cell)
+    cfg, traffic = stand_in("configs", work["config"], cell), stand_in(
+        "traffic", work["traffic"], cell)
+    _, full, mix, _ = spec.cell(benchmark_json, cell)
+    assert set(cfg) <= set(full) and set(traffic) <= set(mix), cell
 
 
 def test_four_chip_cells_are_at_most_half(benchmark_json):
