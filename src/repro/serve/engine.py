@@ -129,6 +129,10 @@ class RetrievalEngine:
             "index_memory_bytes",
             "resident bytes of the served index, by component",
             labelnames=("component",))
+        self._g_build = r.gauge(
+            "index_build_seconds",
+            "wall seconds of the served index's build, by stage",
+            labelnames=("stage",))
         r.register_collector(self._collect_gauges)
         # (query f32 bytes, k) -> (dists (k,), idxs (k,)) numpy rows
         self._cache: "collections.OrderedDict" = collections.OrderedDict()
@@ -157,6 +161,9 @@ class RetrievalEngine:
         mem = index_memory(self.index)
         for comp in _MEMORY_COMPONENTS:
             self._g_memory.set(mem.get(comp, 0), component=comp)
+        for stage, secs in getattr(self.index, "build_seconds",
+                                   {}).items():
+            self._g_build.set(secs, stage=stage)
 
     # -- backward-compatible counter attributes ------------------------------
     # (tests and the miner read these; writes go through the registry)
@@ -297,12 +304,23 @@ class RetrievalEngine:
             rerank_depth=topk_kw.get("rerank",
                                      getattr(self.index, "rerank_depth",
                                              None)))
+        counted = getattr(self.index, "topk_counted", None)
         t0 = self.clock.now()
-        dists, idxs = self.index.topk(q, k, backend=self.backend, **topk_kw)
-        dists, idxs = jax.block_until_ready((dists, idxs))
+        if counted is None:
+            dists, idxs = self.index.topk(q, k, backend=self.backend,
+                                          **topk_kw)
+            work = None
+        else:           # a probed index also says what its scan touched
+            dists, idxs, work = counted(q, k, n, backend=self.backend,
+                                        **topk_kw)
+        dists, idxs, work = jax.block_until_ready((dists, idxs, work))
         dt = self.clock.now() - t0
         d_sp.end()
         self._h_search.observe(dt)
+        if work is not None:
+            for (name, help_), v in zip(self.index.scan_counts,
+                                        np.asarray(work).tolist()):
+                self.registry.counter(f"ivf_{name}_total", help_).inc(v)
 
         dists = np.asarray(dists[:n])
         idxs = np.asarray(idxs[:n])
@@ -339,7 +357,9 @@ class RetrievalEngine:
         Backend extras appear when the index exposes them: delta_rows /
         tombstones / compactions (MutableIndex), code_bytes_per_row /
         compression_ratio (IVFPQIndex), scan_impl (IVF/IVFPQ segment-scan
-        implementation knob). With a traffic front end attached
+        implementation knob), n_clusters / nprobe / cap (IVF/IVFPQ
+        layout) and pad_share (IVFIndex: pad slots over all C*cap).
+        With a traffic front end attached
         (serve/scheduler.py), a ``frontend`` sub-dict adds per-class
         latency percentiles, queue depths, admission/rejection/expiry
         counters, and the current degradation level.
@@ -373,7 +393,10 @@ class RetrievalEngine:
                           ("compactions", "n_compactions"),
                           ("code_bytes_per_row", "code_bytes_per_row"),
                           ("compression_ratio", "compression_ratio"),
-                          ("scan_impl", "scan_impl")):
+                          ("scan_impl", "scan_impl"),
+                          ("n_clusters", "n_clusters"),
+                          ("nprobe", "nprobe"), ("cap", "cap"),
+                          ("pad_share", "pad_share")):
             value = getattr(self.index, attr, None)
             if value is not None:
                 out[key] = value

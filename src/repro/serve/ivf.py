@@ -33,8 +33,10 @@ all-sentinel cluster so every shard does identical static-shaped work.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -45,27 +47,36 @@ from repro.kernels.ivf_scan import ivf_scan_topk
 from repro.kernels.metric_topk import metric_sqdist_factored, project_gallery
 from repro.kernels.metric_topk.kernel import BIG
 from repro.kernels.pairwise_dist.ref import pairwise_sqdist_ref
+from repro.obs import annotate
 from repro.serve import scan
 
 
 # -- metric-space k-means ----------------------------------------------------
+
+def _row_blocks(fn, block_rows: int, *arrays):
+    """``fn`` over row blocks of ``arrays`` (each with M leading rows), the
+    last block zero-padded, so a (rows, C) distance block never
+    materializes whole at big M. ``fn`` maps one block of each array to a
+    tuple of per-row outputs; returns them for the M real rows."""
+    M = arrays[0].shape[0]
+    B = min(block_rows, M)
+    Mp = ((M + B - 1) // B) * B
+    blocks = tuple(jnp.pad(a, [(0, Mp - M)] + [(0, 0)] * (a.ndim - 1))
+                   .reshape(Mp // B, B, *a.shape[1:]) for a in arrays)
+    outs = jax.lax.map(lambda blk: fn(*blk), blocks)
+    return tuple(o.reshape(Mp, *o.shape[2:])[:M] for o in outs)
+
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
 def _assign(gp, centroids, block_rows: int):
     """Nearest-centroid assignment, chunked over rows so the (M, C)
     distance matrix never materializes at big M. Returns (assign (M,)
     int32, min_sqdist (M,) f32)."""
-    M, k = gp.shape
-    B = min(block_rows, M)
-    Mp = ((M + B - 1) // B) * B
-    gpp = jnp.pad(gp, ((0, Mp - M), (0, 0)))
-
     def blk(g):
         d = pairwise_sqdist_ref(g, centroids)
         return jnp.argmin(d, axis=1).astype(jnp.int32), jnp.min(d, axis=1)
 
-    a, md = jax.lax.map(blk, gpp.reshape(Mp // B, B, k))
-    return a.reshape(-1)[:M], md.reshape(-1)[:M]
+    return _row_blocks(blk, block_rows, gp)
 
 
 @functools.partial(jax.jit, static_argnames=("n_clusters",))
@@ -139,38 +150,153 @@ def kmeans_projected(gp, n_clusters: int, *, iters: int = 10, seed: int = 0,
     return centroids, assign, objective
 
 
+@functools.partial(jax.jit, static_argnames=("block_rows",))
+def _own_sqdist(gp, centroids, assign, block_rows: int):
+    """Squared distance of each row to its own cluster's centroid (M,),
+    summed from the difference."""
+    def blk(g, a):
+        return (jnp.sum(jnp.square(g - centroids[a]), axis=1),)
+
+    return _row_blocks(blk, block_rows, gp, assign)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("n_near", "block_rows"))
+def _nearest_open(gp, centroids, full, n_near: int, block_rows: int):
+    """Each row's ``n_near`` nearest centroids among those not ``full``
+    (C,) bool, nearest first: (ids (M, n_near) int32, sqdists (M,
+    n_near))."""
+    shut = jnp.where(full, jnp.inf, 0.0)
+
+    def blk(g):
+        neg, ids = jax.lax.top_k(-(pairwise_sqdist_ref(g, centroids)
+                                   + shut), n_near)
+        return ids.astype(jnp.int32), -neg
+
+    return _row_blocks(blk, block_rows, gp)
+
+
+# nearest open clusters listed per spilled row in one device pass; rows
+# whose list fills up are listed again
+_SPILL_NEAR = 32
+
+
 def _balance_assign(gp, centroids, assign, cap: int) -> np.ndarray:
     """Capacity-bounded assignment: clusters keep their ``cap`` closest
     rows; overflow rows move to the nearest cluster with free space.
 
-    Host-side one-time build step (numpy). Total capacity C*cap >= M is
-    guaranteed by cap >= ceil(M/C), so the greedy pass always places
-    every row.
+    Invariants: no cluster ends over ``cap``; every row is placed; the
+    rows of a cluster within ``cap`` stay; a spilled row lands in the
+    nearest cluster that still had room when it was placed, so every
+    cluster nearer to it ends full. Total capacity C*cap >= M (cap >=
+    ceil(M/C)) guarantees a place for every row.
+
+    Vectorized rounds: every unplaced spilled row proposes to its
+    nearest open cluster, and each cluster takes its nearest proposers
+    up to its room. A round places every row or fills a cluster, so at
+    most C rounds run. Distances run on the device in row blocks (each
+    spilled row's ``_SPILL_NEAR`` nearest open clusters, listed again
+    only for rows whose list has filled up); the host holds index arrays
+    only.
     """
     C = centroids.shape[0]
+    assign = np.asarray(assign)
     counts = np.bincount(assign, minlength=C)
     if counts.max() <= cap:
         return assign
+    gp = jnp.asarray(gp, jnp.float32)
+    centroids = jnp.asarray(centroids, jnp.float32)
+    n_near, block_rows = min(_SPILL_NEAR, C), 16384
+    # rank each row within its cluster, nearest first; ranks >= cap spill
+    own = np.asarray(_own_sqdist(gp, centroids, jnp.asarray(assign),
+                                 block_rows))
+    order = np.lexsort((own, assign))
+    first = np.cumsum(counts) - counts
+    rank = np.empty(len(assign), np.int64)
+    rank[order] = np.arange(len(assign)) - first[assign[order]]
+    rows = np.flatnonzero(rank >= cap)
+    counts = np.minimum(counts, cap)
     assign = assign.copy()
-    spilled = []
-    for c in np.flatnonzero(counts > cap):
-        rows = np.flatnonzero(assign == c)
-        d = np.sum((gp[rows] - centroids[c]) ** 2, axis=1)
-        spilled.extend(rows[np.argsort(d)[cap:]])
-        counts[c] = cap
-    d_all = (np.sum(gp[spilled] ** 2, axis=1)[:, None]
-             + np.sum(centroids ** 2, axis=1)[None, :]
-             - 2.0 * gp[spilled] @ centroids.T)             # (S, C)
-    for i, row in enumerate(spilled):
-        for c in np.argsort(d_all[i]):
-            if counts[c] < cap:
-                assign[row] = c
-                counts[c] += 1
-                break
+
+    chunk = min(block_rows, len(assign))
+
+    def nearest(r):         # in chunks of one shape: a single compile
+        full = jnp.asarray(counts >= cap)
+        out = [_nearest_open(gp[jnp.asarray(np.resize(r[lo:lo + chunk],
+                                                      chunk))],
+                             centroids, full, n_near, block_rows)
+               for lo in range(0, len(r), chunk)]
+        return tuple(np.concatenate([np.asarray(o[i]) for o in out])[:len(r)]
+                     for i in (0, 1))
+
+    near, near_d = nearest(rows)
+    while rows.size:
+        is_open = counts[near] < cap                        # (S, n_near)
+        lost = ~is_open.any(axis=1)
+        if lost.any():      # every listed cluster filled: list again
+            near[lost], near_d[lost] = nearest(rows[lost])
+            continue
+        j = np.argmax(is_open, axis=1)
+        s = np.arange(len(rows))
+        target, dist = near[s, j], near_d[s, j]
+        by = np.lexsort((dist, target))                     # nearest first
+        t = target[by]
+        within = np.arange(len(by)) - np.searchsorted(t, t)
+        take = within < cap - counts[t]
+        assign[rows[by[take]]] = t[take]
+        counts += np.bincount(t[take], minlength=C)
+        keep = by[~take]
+        rows, near, near_d = rows[keep], near[keep], near_d[keep]
     return assign
 
 
+def _slot_rows(assign, n_clusters: int, cap: int) -> np.ndarray:
+    """The cluster-major padded layout: the gallery row of each of the
+    C*cap slots (rows of a cluster in row order), -1 on pad slots."""
+    counts = np.bincount(assign, minlength=n_clusters)
+    order = np.argsort(assign, kind="stable")
+    within = np.arange(len(assign)) - (np.cumsum(counts) - counts)[
+        assign[order]]
+    src = np.full((n_clusters * cap,), -1, np.int32)
+    src[assign[order] * cap + within] = order
+    return src
+
+
+def _lay_out(gp, gn, src):
+    """The padded segments on the device: (gp_pad (C*cap, k) with zero
+    pad rows, gn_pad with BIG on pads, ids_pad = ``src``). Assembled on
+    the host: a gather on the device would hold a row-major copy of the
+    gallery beside both layouts (the TPU stores f32[M, d_out] column-
+    major), about 14 GB at 1M rows."""
+    real = np.flatnonzero(src >= 0)
+    gp_pad = np.zeros((len(src), gp.shape[1]), np.float32)
+    gp_pad[real] = np.asarray(gp)[src[real]]
+    gn_pad = np.full((len(src),), BIG, np.float32)
+    gn_pad[real] = np.asarray(gn)[src[real]]
+    return tuple(map(jnp.asarray, (gp_pad, gn_pad, src)))
+
+
+@contextlib.contextmanager
+def _stage(stages: dict, name: str):
+    """One build stage: a profiler annotation ``ivf.<name>``, and its
+    wall seconds kept in ``stages``."""
+    t = time.perf_counter()
+    with annotate("ivf." + name):
+        yield
+    stages[name] = time.perf_counter() - t
+
+
 # -- the index ---------------------------------------------------------------
+
+# (name, help) of each count a probed scan returns (``_scan_counts``), over
+# a batch's live queries only
+SCAN_COUNTS = (
+    ("probes", "segments probed, summed over queries"),
+    ("rows_probed", "real rows of every probe of every query"),
+    ("rows_read", "real rows of the distinct segments each batch probed"),
+    ("pad_slots", "pad slots scanned, summed over probes"),
+    ("underfilled", "answers holding a -1 id (fewer real rows than k)"),
+)
+
 
 @dataclasses.dataclass(eq=False)
 class IVFIndex:
@@ -201,7 +327,25 @@ class IVFIndex:
     mesh: Optional[jax.sharding.Mesh] = None
     axes: Tuple[str, ...] = ()
     version: int = 0
+    # wall seconds of the build's stages (kmeans, balance, layout) and of
+    # the whole build; empty for an index assembled from saved arrays
+    build_seconds: dict = dataclasses.field(default_factory=dict)
+    # (C,) int32 real rows of each segment, replicated; counted from
+    # ids_pad when not given
+    seg_rows: Optional[jax.Array] = None
     _fns: dict = dataclasses.field(default_factory=dict, repr=False)
+
+    # the counters of a probed scan (``topk_counted``), in its order, with
+    # their help text
+    scan_counts = SCAN_COUNTS
+
+    def __post_init__(self):
+        if self.seg_rows is None:
+            seg = np.count_nonzero(
+                np.asarray(self.ids_pad).reshape(self.n_clusters, self.cap)
+                >= 0, axis=1).astype(np.int32)
+            self.seg_rows = (scan.put_replicated(self.mesh, seg)
+                             if self.axes else jnp.asarray(seg))
 
     @classmethod
     def build(cls, L, gallery, n_clusters: int = 64, nprobe: int = 8,
@@ -256,38 +400,36 @@ class IVFIndex:
         if C > M:                                           # per shard
             raise ValueError(f"n_clusters={C} (after shard round-up) > "
                              f"gallery size {M}")
-        centroids, assign, _ = kmeans_projected(gp, C, iters=iters,
-                                                seed=seed)
-
-        gp_np = np.asarray(gp)
         cap = int(-((-max(cap_factor, 1.0) * M) // C))      # ceil
         cap = ((cap + 7) // 8) * 8
-        assign = _balance_assign(gp_np, np.asarray(centroids),
-                                 np.asarray(assign), cap)
-        counts = np.bincount(assign, minlength=C)
-        order = np.argsort(assign, kind="stable")           # cluster-major
-        offsets = np.cumsum(counts) - counts
-        within = np.arange(M) - offsets[assign[order]]
-        slots = assign[order] * cap + within
-
-        gp_pad = np.zeros((C * cap, k), np.float32)
-        gn_pad = np.full((C * cap,), BIG, np.float32)
-        ids_pad = np.full((C * cap,), -1, np.int32)
-        gp_pad[slots] = gp_np[order]
-        gn_pad[slots] = np.asarray(gn)[order]
-        ids_pad[slots] = order.astype(np.int32)
-
-        gp_pad, gn_pad, ids_pad = map(jnp.asarray, (gp_pad, gn_pad, ids_pad))
+        stages = {}
+        t_build = time.perf_counter()
+        with annotate("ivf.build", rows=M, n_clusters=C, cap=cap):
+            with _stage(stages, "kmeans"):
+                centroids, assign, _ = kmeans_projected(gp, C, iters=iters,
+                                                        seed=seed)
+                jax.block_until_ready(assign)
+            with _stage(stages, "balance"):
+                assign = _balance_assign(gp, centroids, assign, cap)
+            with _stage(stages, "layout"):
+                src = _slot_rows(assign, C, cap)
+                gp_pad, gn_pad, ids_pad = _lay_out(gp, gn, src)
+                jax.block_until_ready(gp_pad)
+        stages["build"] = time.perf_counter() - t_build
+        seg_rows = jnp.asarray(np.count_nonzero(
+            src.reshape(C, cap) >= 0, axis=1).astype(np.int32))
         if axes:
             gp_pad = scan.put_row_sharded(mesh, axes, gp_pad)
             gn_pad = scan.put_row_sharded(mesh, axes, gn_pad)
             ids_pad = scan.put_row_sharded(mesh, axes, ids_pad)
             L = scan.put_replicated(mesh, L)
             centroids = scan.put_replicated(mesh, centroids)
+            seg_rows = scan.put_replicated(mesh, seg_rows)
         return cls(L=jnp.asarray(L), centroids=centroids, gp_pad=gp_pad,
                    gn_pad=gn_pad, ids_pad=ids_pad, cap=cap, n_clusters=C,
                    nprobe=min(nprobe, C), n_rows=M, scan_impl=scan_impl,
-                   mesh=mesh, axes=axes)
+                   mesh=mesh, axes=axes, build_seconds=stages,
+                   seg_rows=seg_rows)
 
     @property
     def size(self) -> int:
@@ -298,6 +440,11 @@ class IVFIndex:
     def n_shards(self) -> int:
         """Mesh shards the segments live on (1 when unsharded)."""
         return scan.n_shards(self.mesh, self.axes)
+
+    @property
+    def pad_share(self) -> float:
+        """Share of the layout's C*cap slots that are pads."""
+        return 1.0 - self.n_rows / (self.n_clusters * self.cap)
 
     def topk(self, queries, k_top: int, backend: str = "xla",
              nprobe: Optional[int] = None,
@@ -322,6 +469,20 @@ class IVFIndex:
         (Nq, k_top) int32); -1 ids mark under-filled probes (raise
         nprobe if callers see them).
         """
+        dists, ids, _ = self._search(queries, k_top, None, backend, nprobe,
+                                     scan_impl)
+        return dists, ids
+
+    def topk_counted(self, queries, k_top: int, live: Optional[int] = None,
+                     backend: str = "xla", nprobe: Optional[int] = None,
+                     scan_impl: Optional[str] = None):
+        """As ``topk``, plus the scan's work on the first ``live`` queries
+        (default all; the rest are a batch's pad rows): an int32 device
+        vector with one entry per count of ``scan_counts``, computed inside
+        the same program as the answers."""
+        return self._search(queries, k_top, live, backend, nprobe, scan_impl)
+
+    def _search(self, queries, k_top, live, backend, nprobe, scan_impl):
         if backend != "xla":
             raise NotImplementedError(
                 "IVFIndex only supports the xla backend")
@@ -349,7 +510,7 @@ class IVFIndex:
             build = (self._build_topk_sharded if self.n_shards > 1
                      else self._build_topk)
             fn = self._fns[key] = build(k_top, np_, impl)
-        return fn(queries)
+        return fn(queries, jnp.shape(queries)[0] if live is None else live)
 
     # -- single-device query path -------------------------------------------
 
@@ -360,17 +521,20 @@ class IVFIndex:
         # the segments ride in as arguments: a jit that closed over them
         # would bake the whole gallery into the program as a constant
         @jax.jit
-        def run(queries, L, centroids, gp_pad, gn_pad, ids_pad):
+        def run(queries, live, L, centroids, seg_rows, gp_pad, gn_pad,
+                ids_pad):
             qp = scan.project_queries(L, queries)
             probes = _probe(qp, centroids, nprobe)
-            return ivf_scan_topk(qp, probes, gp_pad.reshape(C, cap, k),
+            d, i = ivf_scan_topk(qp, probes, gp_pad.reshape(C, cap, k),
                                  gn_pad.reshape(C, cap),
                                  ids_pad.reshape(C, cap), kk=k_top,
                                  block_q=self.block_q,
                                  use_kernel=(impl == "pallas"))
+            return d, i, _scan_counts(probes, seg_rows, i, live, cap)
 
-        return lambda queries: run(queries, self.L, self.centroids,
-                                   self.gp_pad, self.gn_pad, self.ids_pad)
+        return lambda queries, live: run(
+            queries, live, self.L, self.centroids, self.seg_rows,
+            self.gp_pad, self.gn_pad, self.ids_pad)
 
     # -- sharded query path (whole clusters per shard) -----------------------
 
@@ -404,11 +568,31 @@ class IVFIndex:
                                         local_candidates, k_top, n_extras=1)
 
         @jax.jit
-        def run(queries, L, centroids, *arrays):
+        def run(queries, live, L, centroids, seg_rows, *arrays):
             qp = scan.project_queries(L, queries)
-            return inner(qp, _probe(qp, centroids, nprobe), *arrays)
+            probes = _probe(qp, centroids, nprobe)
+            d, i = inner(qp, probes, *arrays)
+            return d, i, _scan_counts(probes, seg_rows, i, live, cap)
 
-        return lambda queries: run(queries, self.L, self.centroids, *arrays)
+        return lambda queries, live: run(queries, live, self.L,
+                                         self.centroids, self.seg_rows,
+                                         *arrays)
+
+
+def _scan_counts(probes, seg_rows, ids_out, live, cap: int):
+    """The work of a probed scan on its first ``live`` queries, in the
+    order of ``SCAN_COUNTS``, from the real rows of each segment
+    ``seg_rows`` (C,)."""
+    is_live = jnp.arange(probes.shape[0]) < live
+    n_probes = jnp.sum(is_live, dtype=jnp.int32) * probes.shape[1]
+    rows_probed = jnp.sum(jnp.where(is_live[:, None], seg_rows[probes], 0))
+    probed = jnp.zeros(seg_rows.shape, jnp.int32).at[probes].max(
+        jnp.broadcast_to(is_live[:, None], probes.shape).astype(jnp.int32))
+    return jnp.stack([
+        n_probes, rows_probed, jnp.sum(probed * seg_rows),
+        n_probes * cap - rows_probed,
+        jnp.sum(is_live & jnp.any(ids_out < 0, axis=1), dtype=jnp.int32),
+    ]).astype(jnp.int32)
 
 
 def _probe(qp, centroids, nprobe: int):

@@ -61,7 +61,7 @@ from repro.kernels.metric_topk import metric_sqdist_factored, project_gallery
 from repro.kernels.metric_topk.kernel import BIG
 from repro.kernels.pq_adc import pq_adc_topk
 from repro.serve import scan
-from repro.serve.ivf import _balance_assign, kmeans_projected
+from repro.serve.ivf import _balance_assign, _slot_rows, kmeans_projected
 
 
 @dataclasses.dataclass(eq=False)
@@ -373,18 +373,12 @@ class IVFPQIndex:
         codes = np.asarray(pq.encode(jnp.asarray(residuals)))
         t = _t_term(pq, codes, cent_np[assign])
 
-        counts = np.bincount(assign, minlength=C)
-        order = np.argsort(assign, kind="stable")           # cluster-major
-        offsets = np.cumsum(counts) - counts
-        within = np.arange(M) - offsets[assign[order]]
-        slots = assign[order] * cap + within
-
+        ids_pad = _slot_rows(assign, C, cap)                # cluster-major
+        real = np.flatnonzero(ids_pad >= 0)
         codes_pad = np.zeros((C * cap, pq.n_subspaces), np.uint8)
         t_pad = np.full((C * cap,), BIG, np.float32)
-        ids_pad = np.full((C * cap,), -1, np.int32)
-        codes_pad[slots] = codes[order]
-        t_pad[slots] = t[order]
-        ids_pad[slots] = order.astype(np.int32)
+        codes_pad[real] = codes[ids_pad[real]]
+        t_pad[real] = t[ids_pad[real]]
 
         return cls(L=jnp.asarray(L, jnp.float32), centroids=centroids,
                    pq=pq, codes_pad=jnp.asarray(codes_pad),

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.serve import (ExactIndex, GalleryIndex, IVFIndex, MetricIndex,
                          RetrievalEngine, kmeans_projected, recall_at_k)
+from repro.serve.ivf import _balance_assign
 
 
 def _clustered(M, d, n_blobs, noise=0.3, seed=0):
@@ -117,6 +118,31 @@ class TestIVFIndex:
         real = ids[ids >= 0]
         assert len(real) == 1000 == len(np.unique(real)), \
             "every gallery row must live in exactly one segment slot"
+        # the spill's own invariants, on the k-means assignment the build
+        # balanced: the hot blob's clusters overflow and spill
+        cent, before, _ = kmeans_projected(G, 8, iters=10, seed=0)
+        before = np.asarray(before)
+        counts = np.bincount(before, minlength=8)
+        assert counts.max() > ivf.cap
+        after = _balance_assign(G, cent, before, ivf.cap)
+        slots = np.flatnonzero(ids >= 0)
+        np.testing.assert_array_equal(after[ids[slots]], slots // ivf.cap)
+        final = np.bincount(after, minlength=8)
+        assert final.max() <= ivf.cap and final.sum() == 1000
+        under = counts[before] <= ivf.cap
+        np.testing.assert_array_equal(after[under], before[under])
+        g, c = np.asarray(G), np.asarray(cent)
+        d = ((g[:, None, :] - c[None]) ** 2).sum(-1)          # (M, C)
+        for row in np.flatnonzero(after != before):
+            nearer = d[row] < d[row, after[row]]
+            assert (final[nearer] == ivf.cap).all(), row
+        # an overflowing cluster keeps its cap nearest rows
+        for cl in np.flatnonzero(counts > ivf.cap):
+            mine = np.flatnonzero(before == cl)
+            kept = mine[after[mine] == cl]
+            assert len(kept) == ivf.cap
+            assert d[kept, cl].max() <= np.delete(
+                d[mine, cl], np.searchsorted(mine, kept)).min()
 
     def test_pallas_backend_rejected(self):
         _, _, q, _, ivf = self._build()

@@ -80,6 +80,27 @@ def test_ivf_scan(one_chip):
              kernel="ivf_scan")
 
 
+def test_ivf_search_at_the_ivf_cell_widths(one_chip):
+    # the benchmark's imnet1m.serve-ivf: IVF4096 over 1M rows, segments of
+    # 312 (no 512-row tile divides them: one tile a segment), nprobe 32,
+    # k 21, one batch of 64; the whole jitted search with its counts
+    from repro.serve import ivf
+    c, cap, nprobe, kk = 4_096, 312, 32, 21
+
+    def search(q, live, L, cent, seg_rows, gp, gn, ids):
+        qp = ivf.scan.project_queries(L, q)
+        probes = ivf._probe(qp, cent, nprobe)
+        d, i = ivf_scan_topk(qp, probes, gp.reshape(c, cap, D_OUT),
+                             gn.reshape(c, cap), ids.reshape(c, cap), kk=kk,
+                             interpret=False)
+        return d, i, ivf._scan_counts(probes, seg_rows, i, live, cap)
+
+    _compile(search, one_chip, ((NQ, D_IN), F32), ((), I32),
+             ((D_OUT, D_IN), F32), ((c, D_OUT), F32), ((c,), I32),
+             ((c * cap, D_OUT), F32), ((c * cap,), F32), ((c * cap,), I32),
+             kernel="ivf_scan")
+
+
 def test_pq_adc(one_chip):
     _compile(lambda tab, dc, pr, codes, t, ids: pq_adc_topk(
                  tab, dc, pr, codes, t, ids, kk=RERANK, interpret=False),
