@@ -2,10 +2,11 @@
 
 The serving hot path: given queries already projected into the metric
 space (``qp = q @ L^T`` (Nq, k), one XLA matmul in ops.py) and a gallery
-that was pre-projected **once** at index build time (``gp = G @ L^T``
-(M, k), ``gn = ||gp||^2`` (1, M)), compute per query the k_top nearest
-gallery rows under the Mahalanobis metric ``M = L^T L`` — in one pass,
-without ever materializing the (Nq, M) distance matrix in HBM:
+that was pre-projected and laid out **once** at index build time
+(``gp = G @ L^T`` (M, kl), ``gn = ||gp||^2`` (1, M); ops.py
+``tile_gallery``), compute per query the k_top nearest gallery rows under
+the Mahalanobis metric ``M = L^T L`` — in one pass, without ever
+materializing the (Nq, M) distance matrix in HBM:
 
     D[:, j]  = ||qp||^2 + gn_j - 2 qp . gp_j (per (bQ, bM) gallery tile)
     best     = stream-merge(best, D tile)    (running top-k in VMEM)
@@ -28,8 +29,13 @@ Tie-breaking matches ``jax.lax.top_k``: equal distances resolve to the
 smaller gallery index (earlier tiles sit first in the candidate row;
 within a tile the index iota ascends; argmin takes the first minimum).
 
-ops.py pads gallery rows to the tile with ``gn = +BIG`` sentinels, so
-padded rows can never enter the top-k; blocks span k whole.
+The gallery arrives tiled (ops.py ``tile_gallery``): rows padded to the
+tile with ``gn = +BIG`` sentinels, so pad rows can never enter the top-k,
+and on a TPU columns padded with zeros to ``kl``, a whole number of
+lanes. The TPU lays an f32 (M, k) array with k off the lane width out
+column-major, and a kernel operand in that layout is copied whole to
+row-major on every call; (M, kl) arrives row-major. Blocks span kl
+whole; the queries' columns are padded to kl here, inside the call.
 """
 
 from __future__ import annotations
@@ -41,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._dispatch import HIGHEST, default_interpret
+from repro.kernels._dispatch import HIGHEST, default_interpret, pad_axis
 
 # Sentinel "infinite" distance for padded gallery rows / best-buffer init.
 # Large enough to lose to any real squared distance, small enough that
@@ -108,16 +114,18 @@ def metric_topk_fused(qp, gp, gn, *, k_top: int = 10,
 
     Args:
       qp: (Nq, k) f32 projected queries.
-      gp: (M, k) f32 pre-projected gallery rows.
+      gp: (M, kl) f32 pre-projected gallery rows, kl >= k (zero columns
+        past k).
       gn: (1, M) f32 squared norms of gp rows (+BIG for padded rows).
       interpret: None compiles on TPU and interprets elsewhere.
 
-    Shapes must tile evenly (ops.py pads otherwise): Nq % block_q == 0 and
-    M % block_m == 0. Returns (dists (Nq, k_top) f32 ascending,
-    indices (Nq, k_top) int32).
+    Shapes must tile evenly (ops.py pads queries, ``tile_gallery`` the
+    gallery): Nq % block_q == 0 and M % block_m == 0. Returns
+    (dists (Nq, k_top) f32 ascending, indices (Nq, k_top) int32).
     """
-    Nq, k = qp.shape
-    M = gp.shape[0]
+    M, k = gp.shape
+    qp = pad_axis(qp, k, 1)
+    Nq = qp.shape[0]
     bQ, bM = min(block_q, Nq), min(block_m, M)
     assert Nq % bQ == 0 and M % bM == 0, (Nq, M, bQ, bM)
     assert gn.shape == (1, M), (gn.shape, M)

@@ -649,7 +649,8 @@ def index_memory(index) -> Dict[str, int]:
     has no such state):
 
       gallery     full-precision projected rows + norms on device
-                  (ExactIndex gp/gn, IVF gp_pad/gn_pad segments);
+                  (ExactIndex gp/gn as stored, pad slots included; IVF
+                  gp_pad/gn_pad segments);
       codes       PQ uint8 codes + per-row t term + codebooks;
       centroids   coarse-quantizer centers (IVF/IVFPQ);
       delta       MutableIndex delta buffer (host projected rows, ids,

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.metric_topk import (metric_sqdist_factored, metric_topk,
-                                       metric_topk_xla, project_gallery)
+                                       metric_topk_xla, project_gallery,
+                                       tile_gallery)
 from repro.serve import scan
 
 
@@ -67,17 +68,28 @@ class MetricIndex(Protocol):
 class ExactIndex:
     """Immutable exact retrieval index over a pre-projected gallery.
 
-    Invariants: ``gp`` holds ``gallery @ L^T`` and ``gn`` its row norms
-    (never recomputed after build); answers are exact for the stored
-    rows, deterministic across backends and shardings (equal distances
-    tie toward the smaller row id); ``version`` only changes when a
-    wrapper (MutableIndex / snapshot load) assigns it — this class never
-    mutates itself.
+    The gallery is held once, in the form its scan reads, made at build:
+
+      * on one device, ``gp`` is the rows tiled to ``(slots, kl)`` and
+        ``gn`` their ``(1, slots)`` norms with ``+BIG`` in the pad slots
+        (kernels/metric_topk.tile_gallery); the fused kernel and the XLA
+        path both read them as they stand;
+      * sharded over ``axes``, ``gp`` is the rows ``(M, k)`` and ``gn``
+        their ``(M,)`` norms, split over the mesh for the shard_map scan.
+
+    ``rows()`` gives the real rows in row form either way. Invariants:
+    the gallery holds ``gallery @ L^T`` and its row norms (never
+    recomputed after build); answers are exact for the stored rows,
+    deterministic across backends and shardings (equal distances tie
+    toward the smaller row id); ``version`` only changes when a wrapper
+    (MutableIndex / snapshot load) assigns it — this class never mutates
+    itself.
     """
 
     L: jax.Array                    # (k, d) replicated metric factor
-    gp: jax.Array                   # (M, k) projected gallery rows
-    gn: jax.Array                   # (M,) row norms of gp
+    gp: jax.Array                   # (slots, kl) tiled, or (M, k) sharded
+    gn: jax.Array                   # (1, slots) tiled, or (M,) sharded
+    n_rows: int                     # real gallery rows M
     mesh: Optional[jax.sharding.Mesh] = None
     axes: Tuple[str, ...] = ()      # mesh axes the rows are sharded over
     version: int = 0
@@ -107,7 +119,9 @@ class ExactIndex:
 
         The mutation/snapshot layer (serve/mutable.py, serve/snapshot.py)
         enters here: compaction folds delta rows and snapshot load restores
-        segments without ever re-projecting the gallery through L.
+        segments without ever re-projecting the gallery through L. On one
+        device the rows are tiled here, once (``tile_gallery``); the
+        caller's ``gp`` is not kept.
         """
         scan.check_metric_factor(L)
         gp = jnp.asarray(gp, jnp.float32)
@@ -116,24 +130,37 @@ class ExactIndex:
                 f"projected rows have dim {gp.shape[1]} but L is "
                 f"{tuple(jnp.shape(L))}; gp must be sized d_out")
         gn = jnp.asarray(gn, jnp.float32)
+        n_rows = gp.shape[0]
         axes: Tuple[str, ...] = ()
         if mesh is not None:
-            axes = scan.gallery_axes(mesh, gp.shape[0], rules)
+            axes = scan.gallery_axes(mesh, n_rows, rules)
         if axes:
             gp = scan.put_row_sharded(mesh, axes, gp)
             gn = scan.put_row_sharded(mesh, axes, gn)
             L = scan.put_replicated(mesh, L)
-        return cls(L=jnp.asarray(L), gp=gp, gn=gn, mesh=mesh, axes=axes)
+        else:
+            gp, gn = tile_gallery(gp, gn)
+        return cls(L=jnp.asarray(L), gp=gp, gn=gn, n_rows=n_rows,
+                   mesh=mesh, axes=axes)
 
     @property
     def size(self) -> int:
         """Number of (real) gallery rows."""
-        return self.gp.shape[0]
+        return self.n_rows
 
     @property
     def n_shards(self) -> int:
         """Mesh shards the rows live on (1 when unsharded)."""
         return scan.n_shards(self.mesh, self.axes)
+
+    def rows(self):
+        """The real gallery rows (gp (M, k), gn (M,)), sliced out of the
+        stored gallery on demand: for the cold readers (snapshots,
+        compaction), never the query path."""
+        if self.axes:
+            return self.gp, self.gn
+        return (self.gp[:self.n_rows, :self.L.shape[0]],
+                self.gn[0, :self.n_rows])
 
     def topk(self, queries, k_top: int, backend: str = "xla"):
         """Exact k nearest gallery rows per query.

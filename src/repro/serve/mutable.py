@@ -424,8 +424,7 @@ class MutableIndex:
         lb = ~self.dead_base
         ld = ~self.dead_delta
         if isinstance(self.base, ExactIndex):
-            gp_b = np.asarray(self.base.gp)[lb]
-            gn_b = np.asarray(self.base.gn)[lb]
+            gp_b, gn_b = (np.asarray(a)[lb] for a in self.base.rows())
         elif isinstance(self.base, IVFPQIndex):
             # the PQ base keeps exact rows in its (host) rerank store,
             # already in base-position order — codes are never decoded

@@ -5,7 +5,8 @@ re-projecting the whole gallery through L (and, for IVF, re-running
 k-means) before the first query can be answered. Snapshots persist the
 *built* device layout instead:
 
-  base.npz      the frozen base index arrays — ExactIndex: L, gp, gn;
+  base.npz      the frozen base index arrays — ExactIndex: L and its
+                real rows gp, gn (tiled again on load);
                 IVFIndex: L, centroids, gp_pad, gn_pad, ids_pad;
                 IVFPQIndex: L, centroids, codebooks, codes_pad, t_pad,
                 ids_pad plus the full-precision rerank store
@@ -72,8 +73,9 @@ def _require_unsharded(index):
 def _base_payload(index):
     """(arrays dict, meta dict) for a frozen base index."""
     if isinstance(index, ExactIndex):
-        return ({"L": np.asarray(index.L), "gp": np.asarray(index.gp),
-                 "gn": np.asarray(index.gn)},
+        gp, gn = index.rows()
+        return ({"L": np.asarray(index.L), "gp": np.asarray(gp),
+                 "gn": np.asarray(gn)},
                 {"base_type": "exact"})
     if isinstance(index, IVFIndex):
         return ({"L": np.asarray(index.L),

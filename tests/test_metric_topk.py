@@ -13,11 +13,13 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.metric_topk import (metric_sqdist_factored, metric_topk,
                                        metric_topk_naive, metric_topk_ref,
-                                       metric_topk_xla, project_gallery)
+                                       metric_topk_xla, project_gallery,
+                                       tile_gallery)
 from repro.serve import (FakeClock, GalleryIndex, MicroBatcher,
                          RetrievalEngine)
 
@@ -40,12 +42,15 @@ class TestMetricTopkKernel:
         (200, 2048, 96, 48, 20),     # queries over several tiles
         (128, 512, 128, 128, 1),     # k_top = 1
         (8, 96, 24, 8, 96),          # k_top = M (full sort)
+        (24, 2500, 40, 24, 10),      # ragged: 572 pad slots past 2 tiles
+        (24, 2500, 40, 24, 21),      # the same at the miner's k
+        (9, 1030, 16, 8, 21),        # 6 rows into a second tile
     ])
     def test_matches_ref(self, Nq, M, d, k, K):
         L, q, G = _data(Nq, M, d, k, seed=Nq + M)
         gp, gn = project_gallery(L, G)
         d_ref, i_ref = metric_topk_ref(q @ L.T, gp, K, gn)
-        d_ker, i_ker = metric_topk(L, q, gp, gn, k_top=K)
+        d_ker, i_ker = metric_topk(L, q, *tile_gallery(gp, gn), k_top=K)
         np.testing.assert_array_equal(np.asarray(i_ker), np.asarray(i_ref))
         np.testing.assert_allclose(np.asarray(d_ker), np.asarray(d_ref),
                                    rtol=1e-4, atol=1e-4)
@@ -58,7 +63,7 @@ class TestMetricTopkKernel:
         # factored/pre-projected path the index serves
         L, q, G = _data(12, 200, 32, 16)
         gp, gn = project_gallery(L, G)
-        _, i_ker = metric_topk(L, q, gp, gn, k_top=8)
+        _, i_ker = metric_topk(L, q, *tile_gallery(gp, gn), k_top=8)
         d_nv, i_nv = metric_topk_naive(L, q, G, 8, chunk=5)
         np.testing.assert_array_equal(np.asarray(i_ker), np.asarray(i_nv))
 
@@ -67,7 +72,8 @@ class TestMetricTopkKernel:
         gp, gn = project_gallery(L, G)
         d_ref, i_ref = metric_topk_ref(q @ L.T, gp, 5, gn)
         d_ker, i_ker = metric_topk(L.astype(jnp.bfloat16),
-                                   q.astype(jnp.bfloat16), gp, gn, k_top=5)
+                                   q.astype(jnp.bfloat16),
+                                   *tile_gallery(gp, gn), k_top=5)
         # bf16 projection perturbs distances; neighbor sets stay mostly put
         overlap = np.mean([
             len(set(np.asarray(i_ker)[i]) & set(np.asarray(i_ref)[i])) / 5
@@ -75,18 +81,67 @@ class TestMetricTopkKernel:
         assert overlap > 0.8
 
     def test_k_top_larger_than_gallery_raises(self):
+        # the index knows the real rows; the ops layer only its slots
         L, q, G = _data(4, 16, 8, 4)
         gp, gn = project_gallery(L, G)
+        gpt, gnt = tile_gallery(gp, gn)
         with pytest.raises(ValueError):
-            metric_topk(L, q, gp, gn, k_top=17)
+            GalleryIndex.build(L, G).topk(q, 17, backend="pallas")
+        with pytest.raises(ValueError):
+            metric_topk(L, q, gpt, gnt, k_top=gpt.shape[0] + 1)
 
-    def test_padded_gallery_rows_never_returned(self):
-        # M=130 pads to 256 inside the kernel; all returned indices real
-        L, q, G = _data(9, 130, 16, 8)
+    @pytest.mark.parametrize("M,K", [
+        (130, 130),                  # 126 pad slots, k_top = M
+        (2500, 10),                  # ragged past two tiles
+        (2500, 21),
+    ])
+    def test_padded_gallery_rows_never_returned(self, M, K):
+        # the tiled gallery has pad slots past M; all returned ids real,
+        # on the kernel and the XLA path alike
+        L, q, G = _data(9, M, 16, 8)
         gp, gn = project_gallery(L, G)
-        _, idx = metric_topk(L, q, gp, gn, k_top=130)
-        assert np.asarray(idx).max() < 130
-        assert np.asarray(idx).min() >= 0
+        gpt, gnt = tile_gallery(gp, gn)
+        assert gpt.shape[0] > M
+        for _, idx in (metric_topk(L, q, gpt, gnt, k_top=K),
+                       metric_topk_xla(L, q, gpt, gnt, K)):
+            assert np.asarray(idx).max() < M
+            assert np.asarray(idx).min() >= 0
+            assert (np.sort(np.asarray(idx), axis=1)[:, 1:]
+                    != np.sort(np.asarray(idx), axis=1)[:, :-1]).all()
+
+    @pytest.mark.parametrize("platform,lanes", [("cpu", 8), ("tpu", 128)])
+    @pytest.mark.parametrize("M", [130, 1000, 1024, 2500])
+    def test_tile_gallery_layout(self, M, platform, lanes, monkeypatch):
+        # rows stay rows; pad rows hold zeros and BIG norms and fill whole
+        # tiles; on a TPU zero columns pad k to the lane width
+        L, _, G = _data(1, M, 16, 8)
+        gp, gn = project_gallery(L, G)
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        gpt, gnt = (np.asarray(a) for a in tile_gallery(gp, gn))
+        slots, tile = gpt.shape[0], 1024 if M > 1024 else 128
+        assert slots % tile == 0 and slots - M < tile
+        assert gpt.shape[1] == lanes and gnt.shape == (1, slots)
+        np.testing.assert_array_equal(gpt[:M, :8], np.asarray(gp))
+        np.testing.assert_array_equal(gnt[0, :M], np.asarray(gn))
+        assert (gpt[M:] == 0).all() and (gpt[:, 8:] == 0).all()
+        assert (gnt[0, M:] == 1e30).all()
+
+    @pytest.mark.parametrize("K", [10, 21])
+    def test_lane_padded_gallery_matches_ref(self, K, monkeypatch):
+        # the gallery as a TPU holds it (zero columns up to the lanes):
+        # kernel and XLA path answer as the oracle on the plain rows
+        L, q, G = _data(24, 1300, 40, 24, seed=5)
+        gp, gn = project_gallery(L, G)
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            gpt, gnt = tile_gallery(gp, gn)
+        assert gpt.shape == (2048, 128)
+        d_ref, i_ref = metric_topk_ref(q @ L.T, gp, K, gn)
+        for d, i in (metric_topk(L, q, gpt, gnt, k_top=K, interpret=True),
+                     metric_topk_xla(L, q, gpt, gnt, K)):
+            np.testing.assert_array_equal(np.asarray(i), np.asarray(i_ref))
+            np.testing.assert_allclose(np.asarray(d), np.asarray(d_ref),
+                                       rtol=1e-4, atol=1e-4)
 
     @pytest.mark.parametrize("M,start", [(400, 0), (410, 0), (390, 20),
                                          (130, 300)])
@@ -100,6 +155,15 @@ class TestMetricTopkKernel:
         part = np.asarray(metric_sqdist_factored(
             qp, gp[start:start + M], gn[start:start + M]))
         np.testing.assert_array_equal(part, full[:, start:start + M])
+        # and so does the XLA scan over a tiled gallery
+        def scan_all(rows):
+            d, i = metric_topk_xla(L, q, *tile_gallery(gp[rows], gn[rows]),
+                                   len(range(520)[rows]))
+            out = np.empty(d.shape, np.float32)
+            np.put_along_axis(out, np.asarray(i), np.asarray(d), 1)
+            return out
+        np.testing.assert_array_equal(scan_all(slice(start, start + M)),
+                                      scan_all(slice(None))[:, start:start + M])
 
 
 class TestServingStack:
@@ -107,6 +171,9 @@ class TestServingStack:
         L, q, G = _data(20, 500, 48, 16)
         index = GalleryIndex.build(L, G)
         d_ref, i_ref = metric_topk_xla(L, q, index.gp, index.gn, 7)
+        i_rows = metric_topk_ref(q @ L.T, *index.rows()[:1], 7,
+                                 index.rows()[1])[1]
+        np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_rows))
         eng = RetrievalEngine(index, k_top=7, buckets=(8, 32))
         dists, idxs = eng.search(q)          # 20 pads to bucket 32
         np.testing.assert_array_equal(idxs, np.asarray(i_ref))

@@ -12,9 +12,13 @@ import pytest
 
 import jax.numpy as jnp
 
+from repro.kernels.metric_topk import project_gallery
+from repro.obs import index_memory
 from repro.serve import (ExactIndex, GalleryIndex, IVFIndex, MetricIndex,
-                         RetrievalEngine, kmeans_projected, recall_at_k)
+                         MutableIndex, RetrievalEngine, kmeans_projected,
+                         recall_at_k)
 from repro.serve.ivf import _balance_assign
+from repro.serve.snapshot import load_index, save_index
 
 
 def _clustered(M, d, n_blobs, noise=0.3, seed=0):
@@ -69,6 +73,71 @@ class TestKMeans:
         gp, _, _ = _clustered(10, 4, 2)
         with pytest.raises(ValueError):
             kmeans_projected(gp, 11)
+
+
+class TestExactIndex:
+    """The single-device ExactIndex holds its gallery once, laid out for
+    the scan at build (kernels/metric_topk.tile_gallery). 1,300 rows fill
+    two tiles of 1,024 slots and leave 748 pad slots, which no reader may
+    see."""
+
+    M, D, K, SLOTS = 1300, 24, 16, 2048
+
+    def _build(self):
+        rng = np.random.RandomState(0)
+        L = jnp.asarray(0.3 * rng.randn(self.K, self.D), jnp.float32)
+        G = jnp.asarray(rng.randn(self.M, self.D), jnp.float32)
+        q = jnp.asarray(rng.randn(12, self.D), jnp.float32)
+        return L, G, q, ExactIndex.build(L, G)
+
+    def test_size_is_the_real_row_count(self):
+        L, G, _, idx = self._build()
+        assert idx.size == self.M
+        assert idx.gp.shape == (self.SLOTS, self.K)
+        assert idx.gn.shape == (1, self.SLOTS)
+        for a, b in zip(idx.rows(), project_gallery(L, G)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("k_top", [10, 21])
+    def test_pallas_matches_xla(self, k_top):
+        _, _, q, idx = self._build()
+        d_x, i_x = idx.topk(q, k_top, backend="xla")
+        d_p, i_p = idx.topk(q, k_top, backend="pallas")
+        np.testing.assert_array_equal(np.asarray(i_p), np.asarray(i_x))
+        np.testing.assert_allclose(np.asarray(d_p), np.asarray(d_x),
+                                   rtol=1e-4, atol=1e-4)
+        assert np.asarray(i_x).max() < self.M
+
+    def test_snapshot_round_trip_returns_rows_bit_for_bit(self, tmp_path):
+        L, G, q, idx = self._build()
+        save_index(idx, str(tmp_path))
+        back = load_index(str(tmp_path))
+        assert back.size == self.M
+        for a, b in zip(back.rows(), project_gallery(L, G)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(back.topk(q, 10), idx.topk(q, 10)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_mutable_compaction_keeps_answers(self):
+        L, G, q, idx = self._build()
+        mut = MutableIndex(idx, L, auto_compact_delta=0, auto_compact_dead=0)
+        rng = np.random.RandomState(1)
+        mut.upsert(rng.randn(40, self.D).astype(np.float32))
+        dead = np.arange(0, 300, 7)
+        mut.delete(dead)
+        d_pre, i_pre = mut.topk(q, 21)
+        assert mut.compact()
+        assert mut.base.size == self.M + 40 - len(dead)
+        d_post, i_post = mut.topk(q, 21)
+        np.testing.assert_array_equal(i_post, i_pre)
+        np.testing.assert_array_equal(d_post, d_pre)
+        assert not np.isin(i_post, dead).any()
+
+    def test_index_memory_counts_one_tiled_copy(self):
+        _, _, _, idx = self._build()
+        mem = index_memory(idx)
+        assert mem["gallery"] == (self.K + 1) * self.SLOTS * 4
+        assert mem["gallery"] == idx.gp.nbytes + idx.gn.nbytes
 
 
 class TestIVFIndex:

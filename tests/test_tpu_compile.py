@@ -7,7 +7,8 @@ may use. Each test here lowers one kernel's ops-layer entry point at
 the widths ``chip_smoke.py`` runs (the paper's ImageNet-1M: d_in 21,504,
 d_out 1,000, 1,000 pairs per step) for one chip of a described v5e
 topology, compiles it, and checks that the kernel is in the program as
-a ``tpu_custom_call``. Nothing runs.
+a ``tpu_custom_call``; the exact search runs at the serving cells' 1M
+rows, and nothing in it may copy the gallery. Nothing runs.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and pytest-xdist
@@ -23,13 +24,12 @@ import pytest
 
 from repro.kernels.dml_pair import dml_pair_loss_fused
 from repro.kernels.ivf_scan import ivf_scan_topk
-from repro.kernels.metric_topk import metric_topk
+from repro.kernels.metric_topk import metric_topk, tile_gallery
 from repro.kernels.pairwise_dist import metric_sqdist_matrix
 from repro.kernels.pq_adc import pq_adc_topk
 
 D_IN, D_OUT, PAIRS = 21_504, 1_000, 1_000       # configs/dml_paper IMNET_1M
 NQ = 64                                          # chip_smoke's serving batch
-GALLERY = 204_800                                # chip_smoke's gallery rows
 C, CAP, NPROBE = 1_024, 256, 16                  # its IVF layout
 S, BITS, RERANK = 20, 8, 256                     # its PQ codes
 
@@ -65,11 +65,44 @@ def _compile(fn, one_chip, *shapes, kernel):
 F32, I32, U8 = jnp.float32, jnp.int32, jnp.uint8
 
 
-def test_metric_topk(one_chip):
-    _compile(lambda L, q, gp, gn: metric_topk(L, q, gp, gn, k_top=10,
-                                              interpret=False),
-             one_chip, ((D_OUT, D_IN), F32), ((NQ, D_IN), F32),
-             ((GALLERY, D_OUT), F32), ((GALLERY,), F32), kernel="metric_topk")
+def _gallery_moves(hlo: str, elements: int):
+    """The copies, pads and transposes in ``hlo`` (fused or not) whose
+    result holds at least ``elements`` elements."""
+    moves = []
+    for m in re.finditer(r"%\S+ = \w+\[([\d,]*)\]\S* "
+                         r"(copy|copy-start|pad|transpose)\(", hlo):
+        n = 1
+        for dim in filter(None, m.group(1).split(",")):
+            n *= int(dim)
+        if n >= elements:
+            moves.append(m.group(0))
+    return moves
+
+
+def _exact_search(one_chip, k_top, monkeypatch):
+    # the operand the single-device ExactIndex holds for the serving
+    # cells' 1M-row gallery, as its build lays it out on a TPU
+    rows = 1_000_000
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        gp, gn = jax.eval_shape(tile_gallery,
+                                jax.ShapeDtypeStruct((rows, D_OUT), F32),
+                                jax.ShapeDtypeStruct((rows,), F32))
+    hlo = _compile(lambda L, q, gp, gn: metric_topk(
+                       L, q, gp, gn, k_top=k_top, interpret=False),
+                   one_chip, ((D_OUT, D_IN), F32), ((NQ, D_IN), F32),
+                   (gp.shape, F32), (gn.shape, F32), kernel="metric_topk")
+    # the search reads the stored gallery as it stands: nothing beside
+    # the kernel copies, pads or re-lays it on a call
+    assert _gallery_moves(hlo, rows * D_OUT) == []
+
+
+def test_metric_topk(one_chip, monkeypatch):
+    _exact_search(one_chip, 10, monkeypatch)
+
+
+def test_metric_topk_at_the_miner_k(one_chip, monkeypatch):
+    _exact_search(one_chip, 21, monkeypatch)
 
 
 def test_ivf_scan(one_chip):
