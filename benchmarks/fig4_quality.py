@@ -21,7 +21,8 @@ import numpy as np
 
 from repro.configs import dml_paper
 from repro.core import dml, itml, kiss, xing2002
-from repro.core.ps.trainer import train_dml_single
+from repro.core.ps.sync import PSConfig
+from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
 from repro.data import pairs as pairdata
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts")
@@ -52,8 +53,9 @@ def run(scale: int = 8, steps: int = 250, seed: int = 0):
 
     # ours (Eq. 4, SGD)
     t0 = time.perf_counter()
-    L, _ = train_dml_single(exp.dml, train_pairs, steps=steps,
-                            batch_size=exp.batch_size, lr=5e-2, seed=seed)
+    tcfg = DMLTrainConfig(dml=exp.dml, ps=PSConfig(n_workers=1, seed=seed),
+                          batch_size=exp.batch_size, steps=steps, lr=5e-2)
+    L, _ = train_dml_distributed(tcfg, train_pairs)
     t_ours = time.perf_counter() - t0
     ap, pr, rc = evaluate(dml.pair_scores(L, xs, ys), labels)
     out["ours"] = {"ap": ap, "train_s": t_ours, "precision": pr, "recall": rc}

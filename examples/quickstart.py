@@ -3,11 +3,11 @@
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import dml
-from repro.core.ps.trainer import train_dml_single
+from repro.core.ps.sync import PSConfig
+from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
 from repro.data import pairs as pairdata
 
 
@@ -22,8 +22,9 @@ def main():
 
     # the paper's reformulated objective:  M = L^T L,  hinge on dissimilars
     dml_cfg = dml.DMLConfig(feat_dim=64, proj_dim=32, lam=1.0, margin=1.0)
-    L, history = train_dml_single(dml_cfg, train_pairs, steps=300,
-                                  batch_size=512, lr=2e-2, seed=0)
+    cfg = DMLTrainConfig(dml=dml_cfg, ps=PSConfig(n_workers=1, seed=0),
+                         batch_size=512, steps=300, lr=2e-2)
+    L, history = train_dml_distributed(cfg, train_pairs)
     print(f"objective: {history[0]['loss']:.3f} -> {history[-1]['loss']:.3f}")
 
     xs, ys = jnp.asarray(eval_pairs["xs"]), jnp.asarray(eval_pairs["ys"])

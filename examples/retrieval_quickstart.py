@@ -24,12 +24,12 @@ loop.
 
 import tempfile
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dml
-from repro.core.ps.trainer import train_dml_single
+from repro.core.ps.sync import PSConfig
+from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
 from repro.data import pairs as pairdata
 from repro.serve import (ExactIndex, IVFIndex, IVFPQIndex, MutableIndex,
                          RetrievalEngine, load_index, recall_at_k,
@@ -39,6 +39,13 @@ from repro.serve import (ExactIndex, IVFIndex, IVFPQIndex, MutableIndex,
 def purity(labels, query_labels, neighbor_ids):
     """Mean fraction of retrieved neighbors sharing the query's class."""
     return float(np.mean(labels[neighbor_ids] == query_labels[:, None]))
+
+
+def train(dml_cfg, train_pairs):
+    """Eq. 4 on one worker: 300 steps of 512 pairs."""
+    cfg = DMLTrainConfig(dml=dml_cfg, ps=PSConfig(n_workers=1, seed=0),
+                         batch_size=512, steps=300, lr=2e-2)
+    return train_dml_distributed(cfg, train_pairs)
 
 
 def main():
@@ -52,8 +59,7 @@ def main():
         n_eval_sim=500, n_eval_dis=500)
 
     dml_cfg = dml.DMLConfig(feat_dim=64, proj_dim=32)
-    L, history = train_dml_single(dml_cfg, train_pairs, steps=300,
-                                  batch_size=512, lr=2e-2, seed=0)
+    L, history = train(dml_cfg, train_pairs)
     print(f"objective: {history[0]['loss']:.3f} -> {history[-1]['loss']:.3f}")
 
     # gallery = first 3500 points; queries = the held-out tail
@@ -84,12 +90,8 @@ def main():
     # serving gallery shrinks by ~D/d'
     from repro.obs import index_memory
 
-    L_sq, _ = train_dml_single(dml.DMLConfig(feat_dim=64, l_rank=64),
-                               train_pairs, steps=300, batch_size=512,
-                               lr=2e-2, seed=0)
-    L_lr, _ = train_dml_single(dml.DMLConfig(feat_dim=64, l_rank=8),
-                               train_pairs, steps=300, batch_size=512,
-                               lr=2e-2, seed=0)
+    L_sq, _ = train(dml.DMLConfig(feat_dim=64, l_rank=64), train_pairs)
+    L_lr, _ = train(dml.DMLConfig(feat_dim=64, l_rank=8), train_pairs)
     idx_sq = ExactIndex.build(L_sq, jnp.asarray(gallery))
     idx_lr = ExactIndex.build(L_lr, jnp.asarray(gallery))
     mem_sq = index_memory(idx_sq)["gallery"]

@@ -12,8 +12,10 @@ the PS literature compares (paper §2) as deterministic SPMD programs over a
                 Hadoop/Spark strawman the paper argues against.
   * ``local`` — Local SGD: each worker takes ``tau`` local steps between
                 parameter all-reduces. tau plays the role of the *average
-                staleness* of the paper's asynchronous PS: compute never
-                blocks on communication; copies drift and are re-merged.
+                staleness* of the paper's asynchronous PS: copies drift and
+                are re-merged. The step computes the merge every step and
+                keeps it every ``tau``th, so the semantics are local SGD's
+                but the all-reduce traffic is not divided by tau.
   * ``ssp``   — Stale Synchronous Parallel (Ho et al. 2013): every step the
                 global mean gradient is computed, but each worker applies a
                 randomly *delayed* copy of it (delay <= s drawn from a
@@ -30,8 +32,7 @@ server-side optimizer applied to aggregated updates.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -203,64 +204,3 @@ def make_train_step(loss_fn: Callable, opt: Optimizer, cfg: PSConfig,
         check_vma=False,
     )
     return jax.jit(shmapped)
-
-
-def make_train_chunk(loss_fn: Callable, opt: Optimizer, cfg: PSConfig,
-                     mesh: Mesh) -> Callable:
-    """Communication-efficient local-SGD: one call = ``tau`` local steps
-    (lax.scan, NO collectives) + a single parameter all-reduce.
-
-    ``make_train_step(sync='local')`` has identical *semantics* (workers
-    blend the synced value on sync steps) but its ``where``-based sync still
-    issues a pmean every step — same convergence, none of the communication
-    saving. This chunked form is what actually divides collective traffic
-    by tau, and is what the §Perf local-SGD measurements lower.
-
-    ``batch`` must be shaped (P, tau, local_batch, ...).
-    """
-    ax = cfg.axis
-
-    def _local(tree):
-        return jax.tree.map(lambda x: x[0], tree)
-
-    def _stack(tree):
-        return jax.tree.map(lambda x: x[None], tree)
-
-    def chunk_fn(state: PSState, batch):
-        params = _local(state.params)
-        opt_state = _local(state.opt_state)
-        batch_l = _local(batch)                     # (tau, B, ...)
-
-        def local_step(carry, b):
-            p, o = carry
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
-            updates, o = opt.update(grads, o, p)
-            p = apply_updates(p, updates)
-            return (p, o), loss
-
-        (params, opt_state), losses = jax.lax.scan(
-            local_step, (params, opt_state), batch_l)
-        # the single "server" merge for the whole chunk
-        params = jax.lax.pmean(params, ax)
-        metrics = {"loss": jax.lax.pmean(jnp.mean(losses), ax)}
-        new_state = PSState(params=_stack(params), opt_state=_stack(opt_state),
-                            step=state.step + cfg.tau, grad_ring=None,
-                            rng=state.rng)
-        return new_state, metrics
-
-    state_specs = PSState(params=P(ax), opt_state=P(ax), step=P(),
-                          grad_ring=None, rng=P())
-    shmapped = jax.shard_map(chunk_fn, mesh=mesh,
-                             in_specs=(state_specs, P(ax)),
-                             out_specs=(state_specs, P()),
-                             check_vma=False)
-    return jax.jit(shmapped)
-
-
-def run_steps(train_step, state: PSState, batches, n_steps: int):
-    """Host loop helper: returns (state, list-of-metrics)."""
-    history = []
-    for _ in range(n_steps):
-        state, metrics = train_step(state, next(batches))
-        history.append(jax.tree.map(float, metrics))
-    return state, history

@@ -1,11 +1,13 @@
-"""High-level distributed DML training loops built on the PS sync layer.
+"""The Eq. 4 training loop on the PS sync layer.
 
-``train_dml_distributed`` is the production-shaped entry point: it takes a
-pair dataset, partitions it over workers (paper §4.1), builds the SPMD PS
-step for the requested consistency model and runs it, returning the merged
-metric plus the objective trace.
+``train_dml_distributed`` is the entry point: it takes a pair dataset or
+a pluggable pair source, partitions it over workers (paper §4.1), builds
+the SPMD PS step for the requested consistency model and runs it,
+returning the merged metric plus the objective trace. One worker is the
+single-device reference. ``_train_steps`` is the loop itself, which the
+closed loop (``mining/loop.ClosedLoopTrainer``) drives too.
 
-Both loops are shape-agnostic in ``d_out``: the trained factor is whatever
+The loop is shape-agnostic in ``d_out``: the trained factor is whatever
 ``DMLConfig.proj_dim`` / ``l_rank`` says — square (d, d) or low-rank
 rectangular (d', d) — and the PS update path (sync.py) treats L as an
 opaque pytree leaf, so rank never appears in the sync logic. A low-rank
@@ -15,13 +17,13 @@ by construction at any rank (no projection step anywhere).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Iterator, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import dml, losses
 from repro.core.ps import sync
@@ -40,11 +42,9 @@ class DMLTrainConfig:
     lr: float = 1e-2
     log_every: int = 10
 
-
-def stack_worker_streams(streams) -> Iterator[dict]:
-    """Zip per-worker batch streams into (P, B, ...) stacked batches."""
-    while True:
-        yield _stacked([next(s) for s in streams])
+    def __post_init__(self):
+        if self.steps < 1:
+            raise ValueError(f"steps must be >= 1, got {self.steps}")
 
 
 def _stacked(bs) -> dict:
@@ -104,11 +104,47 @@ def make_worker_streams(pairs, n_workers: int, batch_size: int, seed: int):
             for i, s in enumerate(shards)]
 
 
-def _stacked_batches(shards, batch_size, seed) -> Iterator[dict]:
-    """Back-compat shim: stream pre-partitioned pair-dict shards."""
-    return stack_worker_streams(
-        [pair_batches(s, batch_size, seed=seed + i)
-         for i, s in enumerate(shards)])
+def _train_steps(cfg: DMLTrainConfig, pairs, opt: Optimizer, mesh,
+                 rng) -> Iterator[tuple]:
+    """The Eq. 4 host loop: builds the loss, the PS step and the batch
+    feed, runs ``cfg.steps`` steps and yields ``(t, state, metrics)``
+    after each one.
+
+    Each iteration's stages are profiler spans (``train`` over the step,
+    ``train.batch`` / ``train.step`` within it, and the streams'
+    ``train.draw`` / ``train.gather`` / ``train.stack``); they reach a
+    trace only while the profiler runs. The yield is inside the step's
+    ``train`` span, so what the caller does there is part of the step.
+    The next step donates ``state`` (one worker), so the caller reads it
+    before asking for that step, and keeps the last one it was given.
+    """
+    state = sync.init_state(opt, dml.init_params(cfg.dml, rng), cfg.ps)
+
+    def loss_fn(L, batch):
+        return losses.dml_pair_loss(L, batch, lam=cfg.dml.lam,
+                                    margin=cfg.dml.margin,
+                                    compute_dtype=cfg.dml.compute_dtype)
+
+    step_fn = sync.make_train_step(loss_fn, opt, cfg.ps, mesh)
+    streams = make_worker_streams(pairs, cfg.ps.n_workers, cfg.batch_size,
+                                  cfg.ps.seed)
+    if len(streams) == 1:
+        step_fn = _one_worker_step(step_fn)
+    batches = _step_batches(streams)
+    in_flight = None
+    for t in range(cfg.steps):
+        with annotate_step("train", t):
+            with annotate("train.batch"):
+                batch = next(batches)
+            with annotate("train.step"):
+                state, metrics = step_fn(state, batch)
+            del batch           # not alive while the next one is made
+            # two steps queued at most: a host that runs ahead of the
+            # device would keep every queued step's batch and L on it
+            if in_flight is not None:
+                jax.block_until_ready(in_flight)
+            in_flight = metrics
+            yield t, state, metrics
 
 
 def train_dml_distributed(cfg: DMLTrainConfig, pairs,
@@ -129,40 +165,10 @@ def train_dml_distributed(cfg: DMLTrainConfig, pairs,
     # seed from the config's explicit seed: dataclass __hash__ varies across
     # Python processes/versions, which silently unseeded distributed runs
     rng = rng if rng is not None else jax.random.PRNGKey(cfg.ps.seed)
-
-    L0 = dml.init_params(cfg.dml, rng)
-    state = sync.init_state(opt, L0, cfg.ps)
-
-    def loss_fn(L, batch):
-        return losses.dml_pair_loss(L, batch, lam=cfg.dml.lam,
-                                    margin=cfg.dml.margin,
-                                    compute_dtype=cfg.dml.compute_dtype)
-
-    step_fn = sync.make_train_step(loss_fn, opt, cfg.ps, mesh)
-    streams = make_worker_streams(pairs, cfg.ps.n_workers, cfg.batch_size,
-                                  cfg.ps.seed)
-    if len(streams) == 1:
-        step_fn = _one_worker_step(step_fn)
-    batches = _step_batches(streams)
-
-    # each iteration's stages are profiler spans (``train`` over the step,
-    # ``train.batch`` / ``train.step`` / ``train.log`` within it, and the
-    # streams' ``train.draw`` / ``train.gather`` / ``train.stack``); they
-    # reach a trace only while the profiler runs
     history = []
-    in_flight = None
-    for t in range(cfg.steps):
-        with annotate_step("train", t):
-            with annotate("train.batch"):
-                batch = next(batches)
-            with annotate("train.step"):
-                state, metrics = step_fn(state, batch)
-            del batch           # not alive while the next one is made
-            # two steps queued at most: a host that runs ahead of the
-            # device would keep every queued step's batch and L on it
-            if in_flight is not None:
-                jax.block_until_ready(in_flight)
-            in_flight = metrics
+    # closed on a raise too, so the step's spans end with it
+    with contextlib.closing(_train_steps(cfg, pairs, opt, mesh, rng)) as it:
+        for t, state, metrics in it:
             if t % cfg.log_every == 0 or t == cfg.steps - 1:
                 with annotate("train.log"):
                     rec = {"step": t, **jax.tree.map(float, metrics)}
@@ -171,38 +177,4 @@ def train_dml_distributed(cfg: DMLTrainConfig, pairs,
                         if out is not None:
                             rec["hook"] = out
                     history.append(rec)
-    L = sync.worker_mean(state.params)
-    return L, history
-
-
-def train_dml_single(dml_cfg: dml.DMLConfig, pairs: dict, steps: int = 200,
-                     batch_size: int = 1000, lr: float = 1e-2, seed: int = 0,
-                     opt: Optional[Optimizer] = None, eval_pairs=None,
-                     eval_every: int = 0):
-    """Single-device reference loop (the t_1 baseline of the speedup curves)."""
-    opt = opt or sgd(lr)
-    L = dml.init_params(dml_cfg, jax.random.PRNGKey(seed))
-    opt_state = opt.init(L)
-
-    def loss_fn(p, b):
-        return losses.dml_pair_loss(p, b, lam=dml_cfg.lam, margin=dml_cfg.margin)
-
-    @jax.jit
-    def step(L, opt_state, batch):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(L, batch)
-        updates, opt_state = opt.update(g, opt_state, L)
-        L = jax.tree.map(lambda p, u: p + u, L, updates)
-        return L, opt_state, loss
-
-    batches = pair_batches(pairs, batch_size, seed=seed)
-    history = []
-    for t in range(steps):
-        L, opt_state, loss = step(L, opt_state, next(batches))
-        rec = {"step": t, "loss": float(loss)}
-        if eval_pairs is not None and eval_every and t % eval_every == 0:
-            scores = dml.pair_scores(L, jnp.asarray(eval_pairs["xs"]),
-                                     jnp.asarray(eval_pairs["ys"]))
-            rec["ap"] = float(dml.average_precision(
-                scores, jnp.asarray(eval_pairs["sim"])))
-        history.append(rec)
-    return L, history
+    return sync.worker_mean(state.params), history

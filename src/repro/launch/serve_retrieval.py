@@ -212,7 +212,8 @@ def main():
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     from repro.core import dml
-    from repro.core.ps.trainer import train_dml_single
+    from repro.core.ps.sync import PSConfig
+    from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
     from repro.data import pairs as pairdata
     from repro.launch.mesh import make_local_mesh
     from repro.serve import (ExactIndex, IVFIndex, IVFPQIndex,
@@ -232,8 +233,10 @@ def main():
         train_pairs, _ = pairdata.train_eval_split(
             cfg, n_train_sim=4000, n_train_dis=4000,
             n_eval_sim=100, n_eval_dis=100)
-        L, hist = train_dml_single(dcfg, train_pairs, steps=args.train_steps,
-                                   batch_size=512, lr=2e-2, seed=0)
+        tcfg = DMLTrainConfig(dml=dcfg, ps=PSConfig(n_workers=1, seed=0),
+                              batch_size=512, steps=args.train_steps,
+                              lr=2e-2)
+        L, hist = train_dml_distributed(tcfg, train_pairs)
         print(f"trained L: objective {hist[0]['loss']:.3f} -> "
               f"{hist[-1]['loss']:.3f}")
     else:

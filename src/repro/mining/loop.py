@@ -22,15 +22,16 @@ pool's metric was current).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
 import jax
 import numpy as np
 
-from repro.core import dml, losses
-from repro.core.ps import sync
-from repro.core.ps.trainer import DMLTrainConfig, stack_worker_streams
+from repro.core import dml
+from repro.core.ps import sync, trainer
+from repro.core.ps.trainer import DMLTrainConfig
 from repro.mining.miner import HardPairMiner, MinerConfig
 from repro.mining.stream import CurriculumSchedule, MinedPairSource
 from repro.optim import Optimizer, sgd
@@ -273,50 +274,44 @@ class ClosedLoopTrainer:
         ``train_dml_distributed``.
         """
         tcfg = self.cfg.train
-        state = sync.init_state(self.opt, self.L0, tcfg.ps)
-
-        def loss_fn(L, batch):
-            return losses.dml_pair_loss(L, batch, lam=tcfg.dml.lam,
-                                        margin=tcfg.dml.margin,
-                                        compute_dtype=tcfg.dml.compute_dtype)
-
-        step_fn = sync.make_train_step(loss_fn, self.opt, tcfg.ps,
-                                       self.mesh)
-        batches = stack_worker_streams(self.source.worker_streams(
-            tcfg.ps.n_workers, tcfg.batch_size, tcfg.ps.seed))
-
         # initial pool under L0: the curriculum starts uniform, but the
         # pool must exist before the ramp's first mined batch (no metric
         # swap — the index was just built with L0)
-        self.refresh(sync.worker_mean(state.params), step=0, swap=False)
+        self.refresh(self.L0, step=0, swap=False)
         last_refresh = 0
         staleness_sum = 0
         trace = []
         history = []
-        for t in range(tcfg.steps):
-            if t > 0 and self._due(t, last_refresh, trace):
-                self.refresh(sync.worker_mean(state.params), step=t)
-                last_refresh = t
-                trace = []           # plateau window restarts post-refresh
-            state, metrics = step_fn(state, next(batches))
-            loss = float(metrics["loss"])
-            trace.append(loss)
-            staleness_sum += t - last_refresh
-            if self.registry is not None:   # per-step staleness gauges
-                self._g_staleness.set(t - last_refresh)
-                self._g_mined_frac.set(self.cfg.schedule.mined_frac(t))
-                self._g_pool.set(self.source.pool_size)
-            if t % tcfg.log_every == 0 or t == tcfg.steps - 1:
-                rec = {"step": t,
-                       **{k: float(v) for k, v in metrics.items()},
-                       "staleness": t - last_refresh,
-                       "mined_frac": self.cfg.schedule.mined_frac(t),
-                       "pool_size": self.source.pool_size}
-                if step_hook is not None:
-                    out = step_hook(t, sync.worker_mean(state.params))
-                    if out is not None:
-                        rec["hook"] = out
-                history.append(rec)
+        # the trainer's own loop; a due refresh runs between its steps,
+        # before the next batch is drawn and before the next step donates
+        # ``state``
+        steps = trainer._train_steps(tcfg, self.source, self.opt,
+                                     self.mesh, self.rng)
+        with contextlib.closing(steps):
+            for t, state, metrics in steps:
+                loss = float(metrics["loss"])
+                trace.append(loss)
+                staleness_sum += t - last_refresh
+                if self.registry is not None:   # per-step staleness gauges
+                    self._g_staleness.set(t - last_refresh)
+                    self._g_mined_frac.set(self.cfg.schedule.mined_frac(t))
+                    self._g_pool.set(self.source.pool_size)
+                if t % tcfg.log_every == 0 or t == tcfg.steps - 1:
+                    rec = {"step": t,
+                           **{k: float(v) for k, v in metrics.items()},
+                           "staleness": t - last_refresh,
+                           "mined_frac": self.cfg.schedule.mined_frac(t),
+                           "pool_size": self.source.pool_size}
+                    if step_hook is not None:
+                        out = step_hook(t, sync.worker_mean(state.params))
+                        if out is not None:
+                            rec["hook"] = out
+                    history.append(rec)
+                nxt = t + 1
+                if nxt < tcfg.steps and self._due(nxt, last_refresh, trace):
+                    self.refresh(sync.worker_mean(state.params), step=nxt)
+                    last_refresh = nxt
+                    trace = []       # plateau window restarts post-refresh
         L = sync.worker_mean(state.params)
         summary = {
             "n_refreshes": self.n_refreshes,

@@ -4,8 +4,8 @@
 ``core/ps/trainer.train_dml_distributed`` accepts (an object with
 ``worker_streams(n_workers, batch_size, seed)``): each worker gets an
 infinite iterator of ``{"xs", "ys", "sim"}`` batches — the exact shape
-``data/pairs.pair_batches`` yields — so mined training drops into
-``_stacked_batches`` unchanged.
+``data/pairs.pair_batches`` yields — so mined training runs through the
+trainer's own loop unchanged.
 
 Each batch mixes two origins under a ratio schedule:
 

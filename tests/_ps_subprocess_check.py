@@ -21,8 +21,14 @@ from repro.core import dml  # noqa: E402
 from repro.core import losses as losses_mod  # noqa: E402
 from repro.core.ps import sync, trainer  # noqa: E402
 from repro.data import pairs as pairdata  # noqa: E402
-from repro.data.loader import partition_pairs  # noqa: E402
 from repro.optim import sgd  # noqa: E402
+
+
+def stacked_batches(pairs, n_workers, batch_size, seed):
+    """The workers' batches stacked on a leading (P, ...) axis, as the
+    trainer feeds several workers' steps."""
+    return trainer._step_batches(trainer.make_worker_streams(
+        pairs, n_workers, batch_size, seed))
 
 
 def main():
@@ -50,7 +56,7 @@ def main():
     mesh = sync.make_worker_mesh(4)
     step = sync.make_train_step(lambda p, b: losses_mod.dml_pair_loss(p, b),
                                 sgd(0.05), ps_cfg, mesh)
-    batches = trainer._stacked_batches(partition_pairs(train_pairs, 4), 64, seed=0)
+    batches = stacked_batches(train_pairs, 4, 64, seed=0)
     for _ in range(3):
         state, _ = step(state, next(batches))
     pstack = np.asarray(state.params)
@@ -70,7 +76,7 @@ def main():
                             ps_local)
     step_l = sync.make_train_step(lambda p, b: losses_mod.dml_pair_loss(p, b),
                                   sgd(0.05), ps_local, mesh)
-    batches = trainer._stacked_batches(partition_pairs(train_pairs, 4), 64, seed=1)
+    batches = stacked_batches(train_pairs, 4, 64, seed=1)
     # after 2 steps (not a sync step), copies must differ
     for _ in range(2):
         state, _ = step_l(state, next(batches))

@@ -107,10 +107,12 @@ class TestTrainingImprovesMetric:
             n_samples=600, feat_dim=32, n_classes=5, noise=1.2, seed=3)
         train_pairs, eval_pairs = pairdata.train_eval_split(
             cfg, 2000, 2000, 500, 500)
-        from repro.core.ps.trainer import train_dml_single
-        dcfg = dml.DMLConfig(feat_dim=32, proj_dim=16)
-        L, hist = train_dml_single(dcfg, train_pairs, steps=150,
-                                   batch_size=256, lr=5e-2)
+        from repro.core.ps.sync import PSConfig
+        from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
+        tcfg = DMLTrainConfig(dml=dml.DMLConfig(feat_dim=32, proj_dim=16),
+                              ps=PSConfig(n_workers=1), batch_size=256,
+                              steps=150, lr=5e-2)
+        L, hist = train_dml_distributed(tcfg, train_pairs)
         xs = jnp.asarray(eval_pairs["xs"]); ys = jnp.asarray(eval_pairs["ys"])
         labels = jnp.asarray(eval_pairs["sim"])
         ap_learned = float(dml.average_precision(dml.pair_scores(L, xs, ys), labels))
@@ -118,6 +120,47 @@ class TestTrainingImprovesMetric:
             dml.pair_scores_euclidean(xs, ys), labels))
         assert hist[-1]["loss"] < hist[0]["loss"]
         assert ap_learned > ap_euclid + 0.02
+
+    def test_one_worker_trainer_is_plain_sgd(self):
+        """One worker is the single-device reference: every step's loss
+        and the final ``L`` equal a plain ``value_and_grad`` + SGD loop on
+        the same ``pair_batches`` from the same seed."""
+        from repro.core import losses
+        from repro.core.ps.sync import PSConfig
+        from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
+        cfg = pairdata.PairDatasetConfig(
+            n_samples=300, feat_dim=16, n_classes=4, noise=1.0, seed=2)
+        pairs, _ = pairdata.train_eval_split(cfg, 600, 600, 50, 50)
+        dcfg = dml.DMLConfig(feat_dim=16, proj_dim=8)
+        tcfg = DMLTrainConfig(dml=dcfg, ps=PSConfig(n_workers=1, seed=5),
+                              batch_size=64, steps=20, lr=5e-2, log_every=1)
+        L, hist = train_dml_distributed(tcfg, pairs)
+
+        grad = jax.jit(jax.value_and_grad(
+            lambda p, b: losses.dml_pair_loss(p, b, lam=dcfg.lam,
+                                              margin=dcfg.margin),
+            has_aux=True))
+        L_ref = dml.init_params(dcfg, jax.random.PRNGKey(5))
+        batches = pairdata.pair_batches(pairs, 64, seed=5)
+        want = []
+        for _ in range(20):
+            (loss, _), g = grad(L_ref, next(batches))
+            L_ref = L_ref - 5e-2 * g
+            want.append(float(loss))
+        assert [h["step"] for h in hist] == list(range(20))
+        np.testing.assert_allclose([h["loss"] for h in hist], want,
+                                   rtol=1e-6)
+        # relative to the factor's norm: entries near zero differ in their
+        # last bits between the two compiled programs
+        assert (np.linalg.norm(np.asarray(L) - np.asarray(L_ref))
+                <= 1e-6 * np.linalg.norm(np.asarray(L_ref)))
+
+    def test_no_steps_rejected(self):
+        from repro.core.ps.sync import PSConfig
+        from repro.core.ps.trainer import DMLTrainConfig
+        with pytest.raises(ValueError, match="steps"):
+            DMLTrainConfig(dml=dml.DMLConfig(feat_dim=4, proj_dim=2),
+                           ps=PSConfig(n_workers=1), steps=0)
 
 
 class TestPairSampling:
@@ -322,11 +365,11 @@ class TestIndexPairSource:
         state = sync.init_state(
             opt, dml.init_params(tcfg.dml, jax.random.PRNGKey(3)), tcfg.ps)
         step = sync.make_train_step(loss_fn, opt, tcfg.ps, mesh)
-        batches = trainer.stack_worker_streams(
-            src.worker_streams(1, 64, seed=3))
+        stream = src.worker_streams(1, 64, seed=3)[0]
         want = []
         for t in range(12):
-            state, m = step(state, next(batches))
+            batch = {k: v[None] for k, v in next(stream).items()}
+            state, m = step(state, batch)
             if t % 5 == 0 or t == 11:
                 want.append(float(m["loss"]))
         np.testing.assert_allclose([h["loss"] for h in hist], want,

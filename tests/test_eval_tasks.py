@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core import dml, eval_tasks
-from repro.core.ps.trainer import train_dml_single
+from repro.core.ps.sync import PSConfig
+from repro.core.ps.trainer import DMLTrainConfig, train_dml_distributed
 from repro.data import pairs as pairdata
 
 
@@ -22,9 +23,10 @@ def trained_metric():
     idx = pairdata.sample_pair_indices(train_y, 4000, 4000, seed=1)
     train_pairs = {"xs": train_x[idx["a"]], "ys": train_x[idx["b"]],
                    "sim": idx["sim"]}
-    dcfg = dml.DMLConfig(feat_dim=48, proj_dim=24)
-    L, _ = train_dml_single(dcfg, train_pairs, steps=250, batch_size=256,
-                            lr=2e-2, seed=0)
+    tcfg = DMLTrainConfig(dml=dml.DMLConfig(feat_dim=48, proj_dim=24),
+                          ps=PSConfig(n_workers=1, seed=0), batch_size=256,
+                          steps=250, lr=2e-2)
+    L, _ = train_dml_distributed(tcfg, train_pairs)
     return L, train_x, train_y, test_x, test_y
 
 

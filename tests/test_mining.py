@@ -250,6 +250,62 @@ class TestClosedLoop:
         with pytest.raises(ValueError, match="staleness policy"):
             self._cfg(refresh_every=0, plateau_window=0)
 
+    def test_one_worker_runs_the_trainers_loop(self, monkeypatch):
+        """The closed loop's steps are the trainer's: one worker's batch
+        reaches the step unstacked, and every step is the trainer's
+        ``train.batch`` / ``train.step`` spans."""
+        from repro.core.ps import trainer
+        stacked, spans = [], []
+        stack, annotate = trainer._stack_batches, trainer.annotate
+
+        def spy_stack(bs):
+            stacked.append(len(bs))
+            return stack(bs)
+
+        def spy_annotate(name, **attrs):
+            spans.append(name)
+            return annotate(name, **attrs)
+
+        monkeypatch.setattr(trainer, "_stack_batches", spy_stack)
+        monkeypatch.setattr(trainer, "annotate", spy_annotate)
+        x, y = _blobs(n=300)
+        _, hist = ClosedLoopTrainer(self._cfg(steps=12, refresh_every=5),
+                                    x, y).run()
+        assert stacked == []
+        assert spans.count("train.step") == spans.count("train.batch") == 12
+        assert hist["summary"]["n_refreshes"] == 3
+
+    # refresh steps and every step's loss of the tiny config at
+    # refresh_every 10, recorded when the closed loop still built its
+    # own step and stacked its one worker's batches
+    GOLDEN_REFRESH_STEPS = [0, 10, 20]
+    GOLDEN_LOSSES = [
+        0.6092922687530518, 0.5941104292869568, 0.5559690594673157,
+        0.7475161552429199, 0.5922776460647583, 0.647750973701477,
+        0.6268375515937805, 0.7137371301651001, 0.7096372842788696,
+        0.9414099454879761, 0.9851362705230713, 0.92722487449646,
+        0.936704158782959, 0.8630478382110596, 0.8654141426086426,
+        0.9407175779342651, 0.8539681434631348, 0.920179009437561,
+        0.8968777656555176, 0.9677304029464722, 0.8992866277694702,
+        0.9397128820419312, 0.9389328956604004, 0.8054518699645996,
+        0.9553326964378357, 0.8372068405151367, 0.9325768947601318,
+        0.9138758182525635, 0.8993270993232727, 1.0445809364318848]
+
+    def test_matches_the_recorded_run(self):
+        """The closed loop's refreshes and losses are the recorded ones:
+        moving its steps into the trainer's loop changed no number."""
+        import dataclasses
+        cfg = self._cfg(refresh_every=10)
+        cfg = dataclasses.replace(
+            cfg, train=dataclasses.replace(cfg.train, log_every=1))
+        x, y = _blobs(n=300)
+        _, hist = ClosedLoopTrainer(cfg, x, y).run()
+        assert ([r["step"] for r in hist["refreshes"]]
+                == self.GOLDEN_REFRESH_STEPS)
+        assert [h["step"] for h in hist["steps"]] == list(range(30))
+        np.testing.assert_allclose([h["loss"] for h in hist["steps"]],
+                                   self.GOLDEN_LOSSES, rtol=1e-5)
+
 
 class TestConvergenceSmoke:
     def test_mined_not_worse_than_uniform_tiny(self):
